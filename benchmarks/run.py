@@ -32,11 +32,12 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import breakdown_fig2, kernel_bench, overhead_table1, sdfg_bench
+    from repro.hw.specs import host_chip
     from repro.trace import artifact_meta
 
     # provenance stamp (schema/git/timestamp/chip) so `python -m repro.trace
     # diff` can compare out_all.json artifacts across PRs
-    results = {"meta": artifact_meta({"fast": args.fast})}
+    results = {"meta": artifact_meta({"fast": args.fast}, chip=host_chip())}
     print("\n########## 1. Table I: instrumentation overhead ##########")
     results["table1"] = overhead_table1.run(fast=args.fast)
     print("\n########## 2. Fig 2: system-vs-user breakdown ##########")
@@ -49,9 +50,11 @@ def main() -> None:
     print("\n########## 5. Roofline table (from dry-run records) ##########")
     recs_path = os.path.join(OUT_DIR, "out_dryrun_single_pod.jsonl")
     if not os.path.exists(recs_path) and args.with_dryrun:
+        # the dry-run compiles for 512 placeholder CPU devices; on a TPU host
+        # it must not try to take the chip this process may hold
         subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun", "--all", "--out", recs_path],
-            check=False,
+            check=False, env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
     if os.path.exists(recs_path):
         from benchmarks import roofline_table

@@ -28,10 +28,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 moved core types under jax.extend
-    from jax.extend import core as jcore
-except ImportError:  # pragma: no cover
-    from jax import core as jcore  # type: ignore
+from jax.extend import core as jcore
 
 from repro.core.events import GLOBAL_LOG, EventLog
 

@@ -28,7 +28,7 @@ from typing import Iterable, Mapping, Optional
 
 import jax
 
-from repro.hw.specs import ChipSpec, default_chip
+from repro.hw.specs import ChipSpec, host_chip
 
 # SDFG component classes (mirrors repro.core.sdfg constants; string-typed to
 # avoid importing jax-heavy modules at registry-definition time).
@@ -58,10 +58,14 @@ class BackendTarget:
 
 
 class BackendRegistry:
-    """Named set of dispatch targets bound to one chip model."""
+    """Named set of dispatch targets bound to one chip model.
+
+    The chip defaults to the one this process runs on: its name stamps every
+    measured sample, and its peaks price the a-priori estimates.
+    """
 
     def __init__(self, chip: Optional[ChipSpec] = None) -> None:
-        self.chip = chip or default_chip()
+        self.chip = chip or host_chip()
         self._targets: dict[str, BackendTarget] = {}
 
     def register(self, target: BackendTarget) -> BackendTarget:

@@ -41,9 +41,9 @@ def _default_key(git_sha: Optional[str], chip: Optional[str]) -> tuple[str, str]
 
         git_sha = current_sha()
     if not chip:
-        from repro.hw.specs import default_chip
+        from repro.hw.specs import stamp_chip
 
-        chip = default_chip().name
+        chip = stamp_chip().name
     return git_sha, chip
 
 

@@ -10,6 +10,9 @@ Flash-decoding adapted to the TPU memory system:
 * Ring-buffer SWA caches are handled by slot-position masking: pos_ids[b, s]
   carries the absolute position held in cache slot s (-1 = empty), the same
   contract as kernels.ref.decode_attention_ref.
+* Mosaic tiling: cur_pos is scalar-prefetched into SMEM (a (1,) VMEM block
+  breaks the 128-lane rule), and pos_ids is viewed as (B, 1, S) so its
+  (1, block_s) block spans a full unit dim for any batch (the (8, 128) rule).
 
 Validated against the ref oracle with interpret=True in tests/test_kernels.py.
 """
@@ -51,8 +54,8 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    cur = cur_ref[0]
-    pos = pos_ref[0]  # (block_s,) int32 slot positions
+    cur = cur_ref[pl.program_id(0)]
+    pos = pos_ref[0]  # (1, block_s) int32 slot positions
     ok = (pos >= 0) & (pos <= cur)
     if window is not None:
         ok &= pos > cur - window
@@ -66,7 +69,7 @@ def _decode_kernel(
         )  # (G, block_s)
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where(ok[None, :], s, NEG_INF)
+        s = jnp.where(ok, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1))
         p = jnp.exp(s - m_new[:, None])
@@ -117,6 +120,7 @@ def decode_attention(
         pos = jnp.pad(pos, ((0, 0), (0, pad_s)), constant_values=-1)
     n_blocks = (S + pad_s) // block_s
     qt = q.reshape(B, Hkv, G, D)
+    pos = pos.reshape(B, 1, S + pad_s)
 
     kernel = functools.partial(
         _decode_kernel,
@@ -127,21 +131,23 @@ def decode_attention(
     )
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, si: (b,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, si: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_s, D), lambda b, h, si: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, block_s, D), lambda b, h, si: (b, h, si, 0)),
-            pl.BlockSpec((1, block_s), lambda b, h, si: (b, si)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, si: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, n_blocks),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, D), lambda b, h, si, cur: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_s, D), lambda b, h, si, cur: (b, h, si, 0)),
+                pl.BlockSpec((1, 1, block_s, D), lambda b, h, si, cur: (b, h, si, 0)),
+                pl.BlockSpec((1, 1, block_s), lambda b, h, si, cur: (b, 0, si)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, si, cur: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         interpret=interpret,
     )(cur_pos.astype(jnp.int32), qt, kt, vt, pos.astype(jnp.int32))
     return out.reshape(B, Hq, D)

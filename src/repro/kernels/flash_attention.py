@@ -12,6 +12,9 @@ TPU-native design (not a CUDA port):
 * Fully-masked tiles (above the causal diagonal, or outside the sliding
   window) skip their matmuls via pl.when — the same work-skipping a GPU kernel
   would get from early-exiting thread blocks.
+* The kernel also writes each row's log-sum-exp, so the custom VJP can run
+  the flash backward (kernels.flash_vjp) from (q, k, v, out, lse) without
+  differentiating through the pallas_call, which Mosaic cannot do.
 
 Validated against kernels.ref.mha_ref with interpret=True in
 tests/test_kernels.py (CPU container; TPU is the lowering target).
@@ -27,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import flash_vjp
+
 NEG_INF = -1e30
 
 
@@ -35,6 +40,7 @@ def _fa_kernel(
     k_ref,
     v_ref,
     o_ref,
+    lse_ref,
     m_scr,
     l_scr,
     acc_scr,
@@ -97,6 +103,7 @@ def _fa_kernel(
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0, ...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        lse_ref[0, 0, ...] = (m_scr[...] + jnp.log(l))[None, :]
 
 
 @functools.partial(
@@ -126,7 +133,25 @@ def flash_attention(
     q_offset: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: (B, Sq, Hq, D); k, v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D)."""
+    """q: (B, Sq, Hq, D); k, v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D).
+
+    Differentiable: the backward is the recompute of kernels.flash_vjp.
+    """
+    return _flash(q, k, v, causal, window, softcap, scale, block_q, block_k,
+                  q_offset, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
+def _flash(q, k, v, causal, window, softcap, scale, block_q, block_k, q_offset,
+           interpret):
+    out, _ = _flash_fwd(q, k, v, causal, window, softcap, scale, block_q,
+                        block_k, q_offset, interpret)
+    return out
+
+
+def _flash_fwd(q, k, v, causal, window, softcap, scale, block_q, block_k,
+               q_offset, interpret):
+    """Runs the kernel; returns (out, lse (B, Hkv, G, Sq) f32)."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     G = Hq // Hkv
@@ -160,7 +185,7 @@ def flash_attention(
         sk=Sk,
         q_offset=q_offset,
     )
-    out = pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=(B, Hq, n_q, n_k),
         in_specs=[
@@ -168,8 +193,15 @@ def flash_attention(
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki, g=G: (b, h // g, ki, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki, g=G: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, n_q * block_q, D), q.dtype),
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+            # (1, block_q) rows of a unit dim: satisfies the (8, 128) rule
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, h, qi, ki: (b, h, 0, qi)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hq, n_q * block_q, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, 1, n_q * block_q), jnp.float32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
@@ -177,4 +209,21 @@ def flash_attention(
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    return out[:, :, :Sq].transpose(0, 2, 1, 3)
+    lse = lse[:, :, 0, :Sq].reshape(B, Hkv, G, Sq)
+    return out[:, :, :Sq].transpose(0, 2, 1, 3), lse
+
+
+def _flash_fwd_rule(q, k, v, causal, window, softcap, scale, block_q, block_k,
+                    q_offset, interpret):
+    out, lse = _flash_fwd(q, k, v, causal, window, softcap, scale, block_q,
+                          block_k, q_offset, interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd_rule(causal, window, softcap, scale, block_q, block_k, q_offset,
+                    interpret, res, dout):
+    return flash_vjp._bwd_rule(causal, window, softcap, scale, q_offset,
+                               flash_vjp.BWD_BLOCK_K, res, dout)
+
+
+_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)

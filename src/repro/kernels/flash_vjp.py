@@ -8,9 +8,9 @@ by the dry-run analyzer).  The flash backward recomputes block scores from
 tiles rather than O(S²) residuals.
 
 Matches kernels.ref.mha_ref forward AND backward (tests/test_kernels_vjp.py).
-This is the TPU-production semantic of the flash_attention Pallas kernel;
-the jnp implementation here is what the dry-run lowers (Pallas→Mosaic needs
-a real TPU target), keeping the compiled HLO representative.
+This is the TPU-production semantic of the flash_attention Pallas kernel,
+whose custom VJP reuses ``_bwd_rule`` below; the jnp forward here is what
+the dry-run lowers, keeping the compiled HLO representative.
 """
 from __future__ import annotations
 
@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+# KV block of the recompute scans (also used by the Pallas forward's VJP)
+BWD_BLOCK_K = 512
 
 
 def _blocks(x: jax.Array, n: int, block: int, axis: int = 1):
@@ -51,7 +53,7 @@ def flash_attention_fused(
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
     q_offset: int = 0,
-    block_k: int = 512,
+    block_k: int = BWD_BLOCK_K,
 ) -> jax.Array:
     out, _ = _fwd_impl(q, k, v, causal, window, softcap, scale, q_offset, block_k)
     return out

@@ -6,10 +6,13 @@ that keeps the recurrent state resident in VMEM:
 
 * The grid is (B, DI/bdi, T/L): chunks innermost, so the (bdi, N) f32 state
   persists in VMEM scratch for the whole sequence sweep of one channel block.
-* Each grid step streams an (L, bdi) x/Δ tile and an (L, N) B/C tile
+* Each grid step streams an (L, bdi) x/Δ tile and an (N, L) B/C tile
   HBM→VMEM, then runs the L recurrence steps on the VPU with zero HBM
   traffic for the state — the selective scan is memory-bound, and this
   tiling reads x/Δ/B/C exactly once (roofline-optimal bytes).
+* Channels sit on the 128 lanes: the state is (N, bdi), so each step's x/Δ
+  row broadcasts over sublanes and its B/C column over lanes.  On the chip
+  the chunk L must be a multiple of 128 (or the whole sequence).
 * Channel blocks (bdi = 512 default) keep state at 512×16×4 B = 32 KB,
   leaving VMEM room for double-buffered input tiles.
 
@@ -26,7 +29,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _mamba_kernel(
-    x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, s0_ref, y_ref, sT_ref, s_scr, y_scr, *, L, n_chunks
+    x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, s0_ref, y_ref, sT_ref,
+    s_scr, x_scr, dt_scr, y_scr, *, L, n_chunks
 ):
     ci = pl.program_id(2)
 
@@ -34,22 +38,22 @@ def _mamba_kernel(
     def _init():
         s_scr[...] = s0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)  # (L, bdi)
-    dt = dt_ref[0].astype(jnp.float32)  # (L, bdi)
-    bm = b_ref[0].astype(jnp.float32)  # (L, N)
-    cm = c_ref[0].astype(jnp.float32)  # (L, N)
-    A = a_ref[...].astype(jnp.float32)  # (bdi, N)
-    D = d_ref[...].astype(jnp.float32)  # (bdi,)
+    # f32 copies of the chunk's x/Δ rows: the step loop reads one row each
+    x_scr[...] = x_ref[0].astype(jnp.float32)  # (L, bdi)
+    dt_scr[...] = dt_ref[0].astype(jnp.float32)
+    bm = b_ref[0].astype(jnp.float32)  # (N, L)
+    cm = c_ref[0].astype(jnp.float32)  # (N, L)
+    A = a_ref[...].astype(jnp.float32)  # (N, bdi)
+    D = d_ref[...].astype(jnp.float32)  # (1, bdi)
+    lane = jax.lax.broadcasted_iota(jnp.int32, bm.shape, 1)
 
-    def step(t, h):
-        xt = jax.lax.dynamic_slice_in_dim(x, t, 1, 0)[0]  # (bdi,)
-        dtt = jax.lax.dynamic_slice_in_dim(dt, t, 1, 0)[0]
-        bt = jax.lax.dynamic_slice_in_dim(bm, t, 1, 0)[0]  # (N,)
-        ct = jax.lax.dynamic_slice_in_dim(cm, t, 1, 0)[0]
-        da = jnp.exp(dtt[:, None] * A)  # (bdi, N)
-        h = da * h + (dtt * xt)[:, None] * bt[None, :]
-        yt = jnp.sum(h * ct[None, :], axis=1) + D * xt  # (bdi,)
-        pl.store(y_scr, (pl.dslice(t, 1), slice(None)), yt[None])
+    def step(t, h):  # h: (N, bdi)
+        xt = x_scr[pl.ds(t, 1), :]  # (1, bdi)
+        dtt = dt_scr[pl.ds(t, 1), :]
+        bt = jnp.sum(jnp.where(lane == t, bm, 0.0), axis=1, keepdims=True)  # (N, 1)
+        ct = jnp.sum(jnp.where(lane == t, cm, 0.0), axis=1, keepdims=True)
+        h = jnp.exp(dtt * A) * h + (dtt * xt) * bt
+        y_scr[pl.ds(t, 1), :] = jnp.sum(h * ct, axis=0, keepdims=True) + D * xt
         return h
 
     h = jax.lax.fori_loop(0, L, step, s_scr[...])
@@ -85,6 +89,8 @@ def mamba_scan(
     assert DI % bdi == 0, f"DI={DI} must be a multiple of block_di={bdi}"
     n_di = DI // bdi
 
+    # channels on lanes throughout: the state is held as (N, DI), and B/C as
+    # (N, T) so each step's (N, 1) column is a lane select, not a transpose
     kernel = functools.partial(_mamba_kernel, L=L, n_chunks=n_chunks)
     y, sT = pl.pallas_call(
         kernel,
@@ -92,24 +98,29 @@ def mamba_scan(
         in_specs=[
             pl.BlockSpec((1, L, bdi), lambda b, di, ci: (b, ci, di)),
             pl.BlockSpec((1, L, bdi), lambda b, di, ci: (b, ci, di)),
-            pl.BlockSpec((1, L, N), lambda b, di, ci: (b, ci, 0)),
-            pl.BlockSpec((1, L, N), lambda b, di, ci: (b, ci, 0)),
-            pl.BlockSpec((bdi, N), lambda b, di, ci: (di, 0)),
-            pl.BlockSpec((bdi,), lambda b, di, ci: (di,)),
-            pl.BlockSpec((1, bdi, N), lambda b, di, ci: (b, di, 0)),
+            pl.BlockSpec((1, N, L), lambda b, di, ci: (b, 0, ci)),
+            pl.BlockSpec((1, N, L), lambda b, di, ci: (b, 0, ci)),
+            pl.BlockSpec((N, bdi), lambda b, di, ci: (0, di)),
+            pl.BlockSpec((1, bdi), lambda b, di, ci: (0, di)),
+            pl.BlockSpec((1, N, bdi), lambda b, di, ci: (b, 0, di)),
         ],
         out_specs=[
             pl.BlockSpec((1, L, bdi), lambda b, di, ci: (b, ci, di)),
-            pl.BlockSpec((1, bdi, N), lambda b, di, ci: (b, di, 0)),
+            pl.BlockSpec((1, N, bdi), lambda b, di, ci: (b, 0, di)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, T, DI), x.dtype),
-            jax.ShapeDtypeStruct((B, DI, N), state.dtype),
+            jax.ShapeDtypeStruct((B, N, DI), state.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bdi, N), jnp.float32),
+            pltpu.VMEM((N, bdi), jnp.float32),
+            pltpu.VMEM((L, bdi), jnp.float32),
+            pltpu.VMEM((L, bdi), jnp.float32),
             pltpu.VMEM((L, bdi), jnp.float32),
         ],
         interpret=interpret,
-    )(x, dt, Bm, C, A, D, state)
-    return y, sT
+    )(
+        x, dt, Bm.transpose(0, 2, 1), C.transpose(0, 2, 1), A.T,
+        D.reshape(1, DI), state.transpose(0, 2, 1),
+    )
+    return y, sT.transpose(0, 2, 1)

@@ -10,6 +10,7 @@ the per-kernel allclose sweeps.  ``impl='ref'`` forces the naive oracle.
 """
 from __future__ import annotations
 
+import functools
 import os
 from contextlib import contextmanager
 from typing import Any, Iterator, Mapping, Optional
@@ -132,6 +133,39 @@ def _interp() -> bool:
     return not _on_tpu()
 
 
+def _per_shard(kernel, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Run a Pallas attention kernel once per shard of the ambient mesh.
+
+    XLA cannot partition a Mosaic kernel, so under a mesh of several devices
+    the call goes through ``shard_map`` in the activation layout that
+    :mod:`repro.distributed.sharding` gives (B, S, H, D) arrays.  Every mesh
+    axis must carry a share: a batch or head count the mesh does not divide
+    is an error, since replicating it would have each device compute all of
+    it.  GQA groups stay whole because Hq and Hkv are cut into the same
+    number of contiguous chunks.
+    """
+    from jax.interpreters import pxla
+
+    from repro.distributed.sharding import ACT_RULES, spec_for
+
+    mesh = pxla.thread_resources.env.physical_mesh
+    if mesh.empty or mesh.size == 1:
+        return kernel(q, k, v)
+    spec = spec_for(q.shape, "batch,seq,heads,head_dim", ACT_RULES, mesh)
+    kv_spec = spec_for(k.shape, "batch,seq,kv_heads,head_dim", ACT_RULES, mesh)
+    used = {a for part in spec if part
+            for a in ((part,) if isinstance(part, str) else part)}
+    if spec != kv_spec or used != set(mesh.axis_names):
+        raise ValueError(
+            f"Pallas attention runs per shard: q {q.shape} and k {k.shape} "
+            f"(B, S, H, D) must split over every axis of mesh "
+            f"{dict(mesh.shape)}, got {spec} and {kv_spec}")
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -150,11 +184,12 @@ def attention(
         window is not None and causal and Sq == Sk and window * 2 < Sk and q_offset == 0
     )
     if impl == "pallas":
-        return _fa_pallas(
-            q, k, v, causal=causal, window=window, softcap=softcap,
+        kernel = functools.partial(
+            _fa_pallas, causal=causal, window=window, softcap=softcap,
             q_offset=q_offset, interpret=_interp(),
             **tuned_overrides("flash_attention", "pallas"),
         )
+        return _per_shard(kernel, q, k, v)
     if impl == "ref":
         return _ref.mha_ref(
             q, k, v, causal=causal, window=window, softcap=softcap, q_offset=q_offset

@@ -5,13 +5,13 @@ is re-blocked for the MXU instead of ported as a per-step GPU loop:
 
 * The grid is (B, H, T/L): chunks are the innermost (sequential) dim, so the
   (K, V) f32 state lives in VMEM scratch across the whole sequence sweep.
-* Within a chunk of L steps the recurrence is closed-form:
-  an (L, L, K) pairwise-decay tensor (exp of log-space cumsum differences,
-  always ≤ 1 so f32-safe) turns the intra-chunk part into two dense matmuls
-  (L×L)·(L×V) — MXU work — while the inter-chunk part is one (L×K)·(K×V)
-  matmul against the carried state.
-* L defaults to 32: the (L, L, K) tensor for K=64 is 512 KB f32 — it fits
-  VMEM next to the r/k/v/w tiles and the state.
+* Within a chunk of L steps the recurrence is closed-form: pairwise decays
+  (exp of log-space cumsum differences, always ≤ 1 so f32-safe) build an
+  (L, L) attention matrix one source column at a time on the VPU, and the
+  intra-chunk part is one dense (L×L)·(L×V) matmul — MXU work — while the
+  inter-chunk part is one (L×K)·(K×V) matmul against the carried state.
+  The log-space cumsum is itself a lower-triangular (L×L)·(L×K) matmul.
+* L defaults to 32, so the column loop unrolls to 31 (L, K) passes.
 
 Validated against kernels.ref.rwkv6_scan_ref with interpret=True.
 """
@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-NEG_INF = -1e30
 
 
 def _rwkv6_kernel(
@@ -40,23 +38,26 @@ def _rwkv6_kernel(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = jnp.log(jnp.clip(w_ref[0, 0].astype(jnp.float32), 1e-38, 1.0))
-    u = u_ref[0].astype(jnp.float32)  # (K,)
+    u = u_ref[0].astype(jnp.float32)  # (1, K)
     s = s_scr[...]  # (K, V)
 
-    cum = jnp.cumsum(lw, axis=0)  # inclusive
-    # intra-chunk pairwise decays: exp(cum_{t-1} - cum_s), strict s < t, always <= 1
-    dmat = (cum - lw)[:, None, :] - cum[None, :, :]  # (L, L, K)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) > jax.lax.broadcasted_iota(
-        jnp.int32, (L, L), 1
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # inclusive cumsum as a lower-triangular matmul (Mosaic has no cumsum)
+    cum = jax.lax.dot_general(
+        (row >= col).astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )
-    dmat = jnp.where(tri[:, :, None], dmat, NEG_INF)
-    att = jnp.sum(r[:, None, :] * jnp.exp(dmat) * k[None, :, :], axis=-1)  # (L, L)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)  # (L,) u-bonus at s == t
-    eye = (
-        jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-        == jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    ).astype(jnp.float32)
-    att = att + diag[:, None] * eye
+    # intra-chunk pairwise decays exp(cum_{t-1} - cum_s), strict s < t, always
+    # <= 1; built one source column s at a time (Mosaic has no 3D relayouts)
+    prev = cum - lw  # cum_{t-1}
+    att = (row == col).astype(jnp.float32) * jnp.sum(
+        r * u * k, axis=-1, keepdims=True
+    )  # u-bonus on the diagonal, s == t
+    for s_ in range(L - 1):
+        dec_s = jnp.exp(jnp.minimum(prev - cum[s_ : s_ + 1], 0.0))  # (L, K)
+        a_s = jnp.sum(r * dec_s * k[s_ : s_ + 1], axis=-1, keepdims=True)  # (L, 1)
+        att = jnp.where((col == s_) & (row > s_), a_s, att)
     intra = jax.lax.dot_general(
         att, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -66,8 +67,14 @@ def _rwkv6_kernel(
     )
     o_ref[0, 0, ...] = (intra + inter).astype(o_ref.dtype)
     # carry: S' = exp(cum_{L-1}) ⊙ S + Σ_s exp(cum_{L-1} - cum_s) k_s v_sᵀ
-    dend = jnp.exp(cum[-1][None, :] - cum)  # (L, K)
-    s_scr[...] = jnp.exp(cum[-1])[:, None] * s + jax.lax.dot_general(
+    last = cum[L - 1 : L, :]  # (1, K) = cum_{L-1}
+    dend = jnp.exp(last - cum)  # (L, K)
+    # the same total as a (K, 1) column, to scale the state's rows
+    last_col = jax.lax.dot_general(
+        lw, jnp.ones((L, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
+    s_scr[...] = jnp.exp(last_col) * s + jax.lax.dot_general(
         k * dend, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
@@ -105,7 +112,8 @@ def rwkv6_scan(
             pl.BlockSpec((1, 1, L, K), lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, 1, L, K), lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, 1, L, K), lambda b, h, ci: (b, h, ci, 0)),
-            pl.BlockSpec((1, K), lambda b, h, ci: (h, 0)),
+            # u viewed as (H, 1, K): a (1, K) block of a unit dim meets (8, 128)
+            pl.BlockSpec((1, 1, K), lambda b, h, ci: (h, 0, 0)),
             pl.BlockSpec((1, 1, K, V), lambda b, h, ci: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -118,5 +126,5 @@ def rwkv6_scan(
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
-    )(rt, kt, vt, wt, u, state)
+    )(rt, kt, vt, wt, u.reshape(H, 1, K), state)
     return out.transpose(0, 2, 1, 3), sT

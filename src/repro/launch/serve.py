@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.dispatch import DispatchConfig, Dispatcher
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm
 from repro.serving.engine import Engine, ServeConfig
 from repro.trace import (
@@ -142,6 +143,7 @@ def main() -> None:
     if args.tune != "off" and args.dispatch == "off":
         # tune winners live in the dispatcher's profile store
         ap.error("--tune requires --dispatch (static|roofline|profiled)")
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -26,6 +26,7 @@ from repro.configs import get_config, reduced
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.dispatch import DispatchConfig, Dispatcher, with_impl
 from repro.distributed import sharding as shd
+from repro.launch.cache import enable_compile_cache
 from repro.runtime.supervisor import FailureInjector, Supervisor, SupervisorConfig
 from repro.trace import (
     Session,
@@ -138,6 +139,7 @@ def main() -> None:
     if args.tune != "off" and args.dispatch == "off":
         # tune winners live in the dispatcher's profile store
         ap.error("--tune requires --dispatch (static|roofline|profiled)")
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

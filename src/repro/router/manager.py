@@ -78,6 +78,15 @@ class ReplicaManager:
     ) -> None:
         if count < 1:
             raise ValueError(f"count must be >= 1 (got {count})")
+        if count > 1 and "--synthetic" not in replica_argv:
+            from repro.hw.specs import tpu_host
+
+            if tpu_host():
+                # each replica is its own JAX process, and a chip belongs to
+                # one process: the second replica would fail or hang
+                raise RuntimeError(
+                    f"{count} real-engine replicas cannot share this TPU host "
+                    "(one process per chip); run one replica, or --synthetic")
         self.count = count
         self.replica_argv = list(replica_argv)
         self.workdir = workdir

@@ -437,7 +437,6 @@ def main(argv: Optional[list[str]] = None) -> int:
     if not args.synthetic and not args.arch:
         ap.error("--arch is required unless --synthetic")
 
-    from repro.hw.specs import default_chip
     from repro.trace.session import git_sha
 
     log = TraceCollector()
@@ -447,10 +446,12 @@ def main(argv: Optional[list[str]] = None) -> int:
             max_batch=args.max_batch,
             ms_per_token=args.synthetic_ms_per_token,
             log=log, metrics=plane.registry)
-        info: dict[str, Any] = {"chip": default_chip().name}
+        info: dict[str, Any] = {"chip": "synthetic"}  # runs on no device
     else:
+        from repro.hw.specs import host_chip
+
         engine, info = _build_real_engine(args, log, plane)
-        info.setdefault("chip", default_chip().name)
+        info.setdefault("chip", host_chip().name)
     info.update({"git_sha": git_sha(), "synthetic": bool(args.synthetic)})
 
     stream = None

@@ -18,11 +18,14 @@ import os
 import subprocess
 import sys
 import time
-from typing import Any, Optional
+from typing import TYPE_CHECKING, Any, Optional
 
 from repro.core.events import Event, EventLog
 from repro.dispatch.profiles import ProfileStore
 from repro.trace.collector import Span, SpanNode, resolve_spans, span_tree
+
+if TYPE_CHECKING:
+    from repro.hw.specs import ChipSpec
 
 SESSION_SCHEMA = "repro.trace.session/v1"
 ARTIFACT_SCHEMA = "repro.bench/v1"
@@ -57,12 +60,17 @@ def run_metadata(extra: Optional[dict[str, Any]] = None) -> dict[str, Any]:
     return meta
 
 
-def artifact_meta(extra: Optional[dict[str, Any]] = None) -> dict[str, Any]:
-    """Stamp for benchmark output JSON (``repro.trace diff``-comparable)."""
-    from repro.hw.specs import default_chip
+def artifact_meta(extra: Optional[dict[str, Any]] = None,
+                  chip: Optional[ChipSpec] = None) -> dict[str, Any]:
+    """Stamp for benchmark output JSON (``repro.trace diff``-comparable).
+
+    ``chip`` is the device the artifact was measured on; without it the
+    stamp never starts a JAX backend (:func:`repro.hw.specs.stamp_chip`).
+    """
+    from repro.hw.specs import stamp_chip
 
     meta = {"schema": ARTIFACT_SCHEMA, **run_metadata(extra)}
-    meta["chip"] = dataclasses.asdict(default_chip())
+    meta["chip"] = dataclasses.asdict(chip or stamp_chip())
     return meta
 
 

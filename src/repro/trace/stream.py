@@ -107,9 +107,9 @@ class StreamingSession:
         self.stats_provider = stats_provider
         self.device_provider = device_provider
         if chip is None:
-            from repro.hw.specs import default_chip
+            from repro.hw.specs import stamp_chip
 
-            chip = dataclasses.asdict(default_chip())
+            chip = dataclasses.asdict(stamp_chip())
         self._manifest: dict[str, Any] = {
             "schema": STREAM_SCHEMA,
             **run_metadata(meta),

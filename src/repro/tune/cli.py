@@ -28,10 +28,10 @@ from repro.tune.space import default_spaces
 
 
 def _env_key() -> tuple[str, str]:
-    from repro.hw.specs import default_chip
+    from repro.hw.specs import stamp_chip
     from repro.trace.session import git_sha
 
-    return git_sha(), default_chip().name
+    return git_sha(), stamp_chip().name
 
 
 def _load_store(args: argparse.Namespace) -> ProfileStore:
@@ -43,17 +43,17 @@ def _load_store(args: argparse.Namespace) -> ProfileStore:
     return store
 
 
-def _fleet_pull(store: ProfileStore, target: str,
-                token: Optional[str]) -> tuple[Optional[FleetPusher], dict]:
+def _fleet_pull(store: ProfileStore, target: str, token: Optional[str],
+                chip: str) -> tuple[Optional[FleetPusher], dict]:
     """Pull + merge matching fleet profiles, return a delta pusher.
 
     Mirrors the drivers' warm-start: stale-stamped entries are aged out
     *before* the merge, and the pusher baseline is taken after it, so a
     sweep only ever pushes its own new samples.
     """
-    from repro.trace.session import age_out_profiles
+    from repro.trace.session import age_out_profiles, git_sha
 
-    sha, chip = _env_key()
+    sha = git_sha()
     client = FleetClient(target, token=token)
     rec: dict = {"target": target}
     try:
@@ -72,14 +72,16 @@ def _fleet_pull(store: ProfileStore, target: str,
 
 def cmd_sweep(args: argparse.Namespace) -> int:
     store = _load_store(args)
-    pusher, fleet_rec = (None, None)
-    if args.fleet:
-        pusher, fleet_rec = _fleet_pull(store, args.fleet, args.token)
     settings = SweepSettings(
         mode=args.mode, warmup=args.warmup, repeats=args.repeats,
         workers=args.workers, prune_ratio=args.prune_ratio,
     )
     explorer = Explorer(store, settings=settings)
+    pusher, fleet_rec = (None, None)
+    if args.fleet:
+        # keyed by the chip the sweep's samples are stamped with
+        pusher, fleet_rec = _fleet_pull(store, args.fleet, args.token,
+                                        explorer.chip.name)
     summary = explorer.sweep(args.ops or None)
     if fleet_rec is not None:
         summary["fleet"] = fleet_rec

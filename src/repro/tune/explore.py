@@ -35,7 +35,7 @@ from typing import Any, Callable, Mapping, Optional
 
 from repro.core.events import GLOBAL_LOG, EventLog
 from repro.dispatch.profiles import ProfileStore, decode_config, encode_config
-from repro.hw.specs import ChipSpec, default_chip
+from repro.hw.specs import ChipSpec, host_chip, stamp_chip, tpu_host
 from repro.tune.prune import DEFAULT_PRUNE_RATIO, RooflinePruner
 from repro.tune.space import KernelSpace, default_spaces
 
@@ -204,10 +204,12 @@ class Explorer:
         settings: Optional[SweepSettings] = None,
     ) -> None:
         self.store = store
-        self.chip = chip or default_chip()
+        self.settings = settings or SweepSettings()
+        # a synthetic sweep measures on no device, so it takes none
+        self.chip = chip or (stamp_chip() if self.settings.mode == "synthetic"
+                             else host_chip())
         self.spaces = spaces if spaces is not None else default_spaces()
         self.log = GLOBAL_LOG if log is None else log
-        self.settings = settings or SweepSettings()
         # sweep samples carry the same provenance stamps dispatcher samples
         # do, so age_out treats tuned points identically
         from repro.trace.session import git_sha
@@ -268,6 +270,13 @@ class Explorer:
 
             if st.workers > 0 and len(tasks) > 1:
                 import multiprocessing
+
+                if tpu_host():
+                    # spawned workers would each need the chip this process
+                    # may already hold: one process per chip
+                    raise RuntimeError(
+                        "tune --workers > 0 cannot run on a TPU host (one "
+                        "process per chip); use --workers 0")
 
                 ctx = multiprocessing.get_context("spawn")
                 with ctx.Pool(min(st.workers, len(tasks))) as pool:

@@ -62,3 +62,34 @@ def test_flash_vjp_no_quadratic_residuals():
 
     b1, b2 = run(S), run(2 * S)
     assert b2 < b1 * 3, (b1, b2)  # linear-ish growth, not 4x (quadratic)
+
+
+@pytest.mark.parametrize(
+    "window,softcap,q_offset",
+    [(None, None, 0), (16, None, 0), (None, 30.0, 0), (16, 50.0, 0), (None, None, 24)],
+)
+def test_pallas_flash_grads_match_oracle(window, softcap, q_offset):
+    """The Pallas kernel's custom VJP (kernel forward + its log-sum-exp,
+    recompute backward) differentiates like plain AD through the oracle."""
+    from repro.kernels.flash_attention import flash_attention
+
+    B, Sq, Hq, Hkv, D = 2, 40, 4, 2, 16
+    Sk = Sq + q_offset
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, Sq, Hq, D))
+    k = jax.random.normal(ks[1], (B, Sk, Hkv, D))
+    v = jax.random.normal(ks[2], (B, Sk, Hkv, D))
+    cot = jax.random.normal(ks[3], (B, Sq, Hq, D))
+    kw = dict(causal=True, window=window, softcap=softcap, q_offset=q_offset)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(ref.mha_ref(q, k, v, **kw) * cot)
+
+    def loss_pallas(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, block_q=16, block_k=16,
+                                       interpret=True, **kw) * cot)
+
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_pl = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(g_pl, g_ref, "qkv"):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4, err_msg=f"d{name}")

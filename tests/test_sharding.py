@@ -89,3 +89,51 @@ def test_shard_bytes_per_device():
     abs_t = {"w": jax.ShapeDtypeStruct((64, 64), jax.numpy.float32)}
     specs = {"w": P("data", "model")}
     assert shd.shard_bytes_per_device(abs_t, specs, mesh) == 64 * 64 * 4 // 8
+
+
+_PER_SHARD_SCRIPT = r"""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh
+from repro.kernels import ops, ref
+mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+ks = jax.random.split(jax.random.PRNGKey(0), 3)
+q = jax.random.normal(ks[0], (4, 32, 4, 16))
+k = jax.random.normal(ks[1], (4, 32, 2, 16))
+v = jax.random.normal(ks[2], (4, 32, 2, 16))
+want = ref.mha_ref(q, k, v, causal=True)
+loss = lambda q, k, v: ops.attention(q, k, v, impl="pallas").sum()
+with mesh:
+    got = jax.jit(lambda q, k, v: ops.attention(q, k, v, impl="pallas"))(q, k, v)
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+want_g = jax.grad(lambda q, k, v: ref.mha_ref(q, k, v, causal=True).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+for a, b in zip(grads, want_g):
+    np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+# a batch the data axis does not divide is refused, never replicated
+try:
+    with mesh:
+        jax.jit(lambda q, k, v: ops.attention(q, k, v, impl="pallas"))(
+            q[:3], k[:3], v[:3])
+except ValueError as e:
+    assert "per shard" in str(e), e
+else:
+    raise AssertionError("indivisible batch was replicated")
+print("OK")
+"""
+
+
+def test_pallas_attention_runs_per_shard_under_a_mesh():
+    """XLA cannot partition a Pallas kernel: under a 2x2 mesh, attention (and
+    its gradient) goes through shard_map and still matches the oracle; a
+    batch that does not split over the mesh is refused."""
+    import os
+    import subprocess
+    import sys
+
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    out = subprocess.run([sys.executable, "-c", _PER_SHARD_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().endswith("OK")
