@@ -17,6 +17,12 @@ Properties needed at cluster scale, preserved here:
   format for per-shard files (e.g. OCDBT) without touching this interface.
 * **Atomicity** — writes land in ``<dir>/.tmp_stepN`` and are renamed only
   after fsync, so a killed writer never leaves a half checkpoint visible.
+
+Spans: an :class:`AsyncCheckpointer` records each save in its ``EventLog``
+(``GLOBAL_LOG`` unless given one) as ``ckpt.wait_prev`` (joining the
+previous write) then ``ckpt.snapshot`` (the device→host copy), both in the
+caller's thread, and ``ckpt.write`` (serialise, fsync, rename, gc) on the
+writer thread, parented to the span that was open when ``save`` was called.
 """
 from __future__ import annotations
 
@@ -28,6 +34,8 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+
+from repro.core.events import GLOBAL_LOG, EventLog, current_span
 
 PyTree = Any
 
@@ -114,20 +122,26 @@ class AsyncCheckpointer:
     in-flight checkpoint at a time (back-pressure, matching real stores).
     """
 
-    def __init__(self, directory: str, keep: int = 3) -> None:
+    def __init__(self, directory: str, keep: int = 3,
+                 log: Optional[EventLog] = None) -> None:
         self.directory = directory
         self.keep = keep
+        self.log = GLOBAL_LOG if log is None else log
         self._thread: Optional[threading.Thread] = None
         self._error: Optional[BaseException] = None
 
     def save(self, step: int, state: PyTree) -> None:
-        self.wait()
-        host_state = jax.device_get(state)
+        with self.log.lifecycle("ckpt.wait_prev", step):
+            self.wait()
+        with self.log.lifecycle("ckpt.snapshot", step):
+            host_state = jax.device_get(state)
+        parent = current_span()  # contextvars do not cross into the thread
 
         def _run():
             try:
-                _write(self.directory, step, host_state)
-                self._gc()
+                with self.log.lifecycle("ckpt.write", step, parent=parent):
+                    _write(self.directory, step, host_state)
+                    self._gc()
             except BaseException as e:  # surfaced on next wait()
                 self._error = e
 

@@ -26,16 +26,24 @@ Two properties mirror the kernel-side perf machinery:
   instead of whichever span another thread happens to have open.  The
   resulting parent links are what :func:`repro.trace.collector.span_tree`
   folds into host/device timeline trees.
+* **Profiler spans** — while a ``lifecycle()`` block runs, it also holds a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` whose metadata
+  carries ``span`` and ``parent``.  When a profiler is capturing, every
+  lifecycle span is then in its ``.xplane.pb`` host plane, on the same time
+  base as the device's operations; when none is, the annotation is a ~1 us
+  no-op.  The class is taken from an already-imported ``jax``: this module
+  never imports it, so a process without JAX pays one dict lookup.
 """
 from __future__ import annotations
 
 import contextvars
 import dataclasses
 import itertools
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Iterator, Optional
 
 _SPAN_IDS = itertools.count(1)  # process-unique span ids (0 = "no span")
@@ -46,6 +54,14 @@ _SPAN_IDS = itertools.count(1)  # process-unique span ids (0 = "no span")
 _SPAN_STACK: contextvars.ContextVar[tuple[int, ...]] = contextvars.ContextVar(
     "repro_span_stack", default=()
 )
+
+
+def _annotation(name: str, span: int, parent: int) -> Any:
+    """``repro.<name>`` profiler span, or a null context where JAX is not loaded."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return nullcontext()
+    return profiler.TraceAnnotation(f"repro.{name}", span=span, parent=parent)
 
 
 def next_span_id() -> int:
@@ -221,7 +237,8 @@ class EventLog:
         block, and is itself parented to the span that encloses it —
         ``parent=`` overrides that for brackets whose causal parent is not
         the lexically enclosing one (e.g. a checkpoint recorded after its
-        step closed).
+        step closed, or a write on another thread).  The block also runs
+        inside the profiler span ``repro.<name>`` (module docstring).
         """
         span = next_span_id()
         if parent is None:
@@ -229,7 +246,8 @@ class EventLog:
         self.record("spawn", name, payload, span=span, parent=parent)
         token = _SPAN_STACK.set(_SPAN_STACK.get() + (span,))
         try:
-            yield span
+            with _annotation(name, span, parent):
+                yield span
         finally:
             _SPAN_STACK.reset(token)
             self.record("exit", name, payload, span=span, parent=parent)

@@ -17,6 +17,14 @@ faults (the CPU container has one real device; the *mechanisms* are identical):
   not a format migration).
 * **Lifecycle tracing** — step / checkpoint / restart spawn-exit events flow
   into the paper's EventLog (Adaptyst's thread/process-tracing analogue).
+  Each ``step`` span holds four children, in order: ``batch`` (the
+  ``batch_fn`` call), ``dispatch`` (the jitted or dispatched step, until it
+  returns), ``device_wait`` (``block_until_ready`` of its metrics) and
+  ``fetch`` (``device_get`` of the metrics, the straggler check and the
+  bookkeeping before the next step).  A ``checkpoint`` span holds the
+  checkpointer's ``ckpt.*`` spans (:mod:`repro.checkpoint.store`).  Under a
+  profiler each is also the host span ``repro.<name>``
+  (:mod:`repro.core.events`).
 """
 from __future__ import annotations
 
@@ -101,7 +109,7 @@ class Supervisor:
         self.state_shardings = state_shardings
         self.log = GLOBAL_LOG if log is None else log
         self.failures = failures or FailureInjector()
-        self.ckpt = AsyncCheckpointer(cfg.ckpt_dir)
+        self.ckpt = AsyncCheckpointer(cfg.ckpt_dir, log=self.log)
         self.step = 0
         self.restarts = 0
         self.stragglers = 0
@@ -133,6 +141,18 @@ class Supervisor:
         window = self._durations[-self.cfg.straggler_window:]
         return self.cfg.straggler_factor * statistics.median(window)
 
+    def _call(self, batch: Any) -> tuple[PyTree, Any]:
+        """One step on ``batch``: the dispatched variant, else ``train_step``."""
+        if self.dispatcher is not None and self.step_variants:
+            if self._configs is None:
+                self._configs = self.dispatcher.active_configs()
+            return self.dispatcher.dispatch(
+                "train_step", self.step_variants, self.state, batch,
+                sig=signature(batch),  # state pytree is fixed-shape
+                configs=self._configs,
+            )
+        return self.train_step(self.state, batch)
+
     def run(self) -> dict[str, Any]:
         metrics_hist = []
         if latest_step(self.cfg.ckpt_dir) is None:
@@ -145,31 +165,25 @@ class Supervisor:
                 with self.log.lifecycle("step", self.step) as step_span:
                     self.failures.maybe_fail(self.step)
                     t0 = time.monotonic()
-                    batch = self.batch_fn(self.step)
-                    if self.dispatcher is not None and self.step_variants:
-                        # inside the step's span scope: the dispatch event
-                        # lands in the span tree as the step's child
-                        if self._configs is None:
-                            self._configs = self.dispatcher.active_configs()
-                        self.state, metrics = self.dispatcher.dispatch(
-                            "train_step", self.step_variants, self.state, batch,
-                            sig=signature(batch),  # state pytree is fixed-shape
-                            configs=self._configs,
-                        )
-                    else:
-                        self.state, metrics = self.train_step(self.state, batch)
-                    jax.block_until_ready(metrics)
+                    with self.log.lifecycle("batch"):
+                        batch = self.batch_fn(self.step)
+                    with self.log.lifecycle("dispatch"):
+                        # the dispatcher's decision event lands in the span
+                        # tree under this span, inside the step
+                        self.state, metrics = self._call(batch)
+                    with self.log.lifecycle("device_wait"):
+                        jax.block_until_ready(metrics)
                     dt = time.monotonic() - t0
-                deadline = self._deadline()
-                if deadline is not None and dt > deadline:
-                    self.stragglers += 1
-                    # recorded after the step closed, but caused by it: the
-                    # explicit parent keeps the tree causal, not lexical
-                    self.log.record("straggler", "step", {"step": self.step, "s": dt},
-                                    parent=step_span)
-                self._durations.append(dt)
-                metrics_hist.append(jax.device_get(metrics))
-                self.step += 1
+                    with self.log.lifecycle("fetch"):
+                        deadline = self._deadline()
+                        if deadline is not None and dt > deadline:
+                            self.stragglers += 1
+                            self.log.record("straggler", "step",
+                                            {"step": self.step, "s": dt},
+                                            parent=step_span)
+                        self._durations.append(dt)
+                        metrics_hist.append(jax.device_get(metrics))
+                        self.step += 1
                 if self.step % self.cfg.ckpt_every == 0:
                     with self.log.lifecycle("checkpoint", self.step, parent=step_span):
                         self.ckpt.save(self.step, self.state)
@@ -191,16 +205,4 @@ class Supervisor:
             "restarts": self.restarts,
             "stragglers": self.stragglers,
             "metrics": metrics_hist,
-            # collector health: a bounded log on a long run drops oldest
-            # events; surfacing the counter here keeps the loss visible in
-            # every driver's JSON output (perf "lost samples" discipline).
-            "trace": {
-                "events": len(self.log),
-                "dropped": self.log.dropped,
-                "capacity": self.log.maxlen,
-                # cheap per-track/shed counters when the log is a collector
-                # (no span resolution: run() may be mid-restart churn)
-                **(self.log.drop_counters()
-                   if hasattr(self.log, "drop_counters") else {}),
-            },
         }

@@ -31,7 +31,6 @@ from repro.dispatch.cost import estimate_callable
 from repro.dispatch.dispatcher import Dispatcher, with_impl
 from repro.dispatch.profiles import signature
 from repro.models import lm
-from repro.trace.liveprof import device_annotation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,11 +201,8 @@ class Engine:
             req.t_active = time.monotonic()
             # the prefill (and the dispatch decision it triggers) must nest
             # under the request span, whose bracket events live elsewhere;
-            # the device annotation stamps the prefill span id onto every
-            # profiler slice launched inside it
-            with span_scope(req.span), \
-                    self.log.lifecycle("prefill", req.rid) as psid, \
-                    device_annotation(psid):
+            # the lifecycle's profiler span carries the prefill span id
+            with span_scope(req.span), self.log.lifecycle("prefill", req.rid):
                 tokens = jnp.asarray(req.prompt, jnp.int32)[None]
                 logits, new_caches = self._prefill(self.params, tokens)
                 self.caches = jax.tree.map(
@@ -230,8 +226,7 @@ class Engine:
         tokens = np.zeros(B, np.int32)
         for r in live:
             tokens[r.slot] = r.out[-1]
-        with self.log.lifecycle("decode_tick", len(live)) as dsid, \
-                device_annotation(dsid):
+        with self.log.lifecycle("decode_tick", len(live)):
             logits, self.caches = self._decode(
                 self.params,
                 jnp.asarray(tokens),

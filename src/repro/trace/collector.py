@@ -59,12 +59,19 @@ TRACK_OF: dict[str, str] = {
     "replica": "router",
     "step": "step",
     "train_step": "step",
+    "batch": "step",
+    "dispatch": "step",
+    "device_wait": "step",
+    "fetch": "step",
     "microbatch": "microbatch",
     "request": "request",
     "rpc": "request",
     "prefill": "request",
     "decode_tick": "request",
     "checkpoint": "checkpoint",
+    "ckpt.wait_prev": "checkpoint",
+    "ckpt.snapshot": "checkpoint",
+    "ckpt.write": "checkpoint",
     "restart": "checkpoint",
     "elastic_resize": "checkpoint",
     "controller": "controller",

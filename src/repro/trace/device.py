@@ -63,6 +63,8 @@ class DeviceSlice:
         v = self.args.get("span")
         if isinstance(v, int) and v > 0:
             return v
+        if isinstance(v, str) and v.isdigit() and int(v) > 0:
+            return int(v)  # trace-viewer JSON writes stats as text
         for text in (str(v) if v is not None else "", self.name):
             m = _SPAN_HINT_RE.search(text)
             if m:

@@ -22,11 +22,13 @@ second, device-specific budget loop
    and stretches the off time, because the per-window cost is largely
    fixed — to hold measured overhead under ``--trace-overhead-budget-pct``.
 
-Alignment is exact rather than fuzzy because the dispatch and engine paths
-wrap device work in ``jax.profiler.TraceAnnotation(f"span={sid}")``
-(via :func:`device_annotation`), so the profiler's own slices carry the
-host span id and ``align_device_slices`` binds them directly instead of
-falling back to time-window containment.
+Alignment is exact wherever a slice carries its host span id, and falls
+back to time-window containment elsewhere.  The dispatch path wraps device
+work in ``jax.profiler.TraceAnnotation(f"span={sid}")`` (via
+:func:`device_annotation`).  Every lifecycle span (the engine's ``prefill``
+and ``decode_tick`` among them) is a ``repro.<name>`` annotation with a
+``span`` stat (:mod:`repro.core.events`), which ``DeviceSlice.span_hint``
+reads as an integer or as the text the trace viewer's JSON writes.
 
 Degradation is graceful: a missing/failing profiler backend (or a CPU-only
 jax whose dump holds raw xplane protos and no chrome trace) records **one**

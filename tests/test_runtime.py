@@ -125,3 +125,31 @@ def test_straggler_detection(tmp_path, key):
     out = sup.run()
     assert out["stragglers"] >= 1
     assert sup.log.events("straggler")
+
+
+def test_supervisor_step_and_checkpoint_spans(tmp_path):
+    """Each step holds batch, dispatch, device_wait, fetch in that order; the
+    closing checkpoint holds the save's spans, the write's across its thread."""
+    step = jax.jit(lambda s, b: ({"w": s["w"] + b}, {"loss": jnp.sum(b)}))
+    log = EventLog()
+    sup = Supervisor(SupervisorConfig(ckpt_dir=str(tmp_path), ckpt_every=100, max_steps=3),
+                     step, lambda i: jnp.full((4,), float(i)), {"w": jnp.zeros(4)},
+                     log=log)
+    assert sup.ckpt.log is log
+    out = sup.run()
+    assert out["steps"] == 3 and "trace" not in out
+    spawns = log.events(kind="spawn")
+
+    def children(span):
+        return [e.name for e in spawns if e.parent == span]
+
+    steps = [e for e in spawns if e.name == "step"]
+    assert [e.payload for e in steps] == [0, 1, 2]
+    for s in steps:
+        assert children(s.span) == ["batch", "dispatch", "device_wait", "fetch"]
+    closing = [e for e in spawns if e.name == "checkpoint"][-1]
+    assert closing.payload == 3
+    assert children(closing.span) == ["ckpt.wait_prev", "ckpt.snapshot", "ckpt.write"]
+    for name in ("ckpt.wait_prev", "ckpt.snapshot", "ckpt.write"):
+        assert len(log.durations(name)) == 2  # the step-0 save and the closing one
+    assert latest_step(str(tmp_path)) == 3

@@ -431,6 +431,19 @@ def test_device_events_align_under_host_spans(tmp_path):
     assert len({e.span for e in merged}) == len(merged)
 
 
+def test_span_stat_as_text_binds_through_align(tmp_path):
+    """A lifecycle annotation's ``span`` stat, written as text by the trace
+    viewer's JSON, binds its slice to that span outside every window."""
+    rows = _device_dump_rows()[:2] + [
+        {"ph": "X", "pid": 10, "tid": 1, "name": "repro.prefill",
+         "ts": 5_900_000, "dur": 50_000, "args": {"span": "2", "parent": "1"}},
+    ]
+    slices = load_profiler_trace(_write_profiler_dump(tmp_path, rows))
+    assert [s.span_hint for s in slices] == [2]
+    merged = align_device_slices(_golden_events(), slices, offset_s=0.0)
+    assert [(e.name, e.parent, e.payload["align"]) for e in merged] == [
+        ("repro.prefill", 2, "span")]
+
 def test_merge_device_trace_into_session_report_and_export(tmp_path):
     sess = Session(meta={}, events=_golden_events())
     dump = _write_profiler_dump(tmp_path, _device_dump_rows())
