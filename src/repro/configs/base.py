@@ -83,7 +83,9 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
     activation_dtype: str = "bfloat16"
     moment_dtype: str = "float32"  # bf16 for the very large archs (398B on 16GiB chips)
-    remat_policy: str = "nothing"  # nothing | dots | everything (= no remat)
+    # nothing (recompute each period, but keep the Pallas attention's out and
+    # lse) | dots | everything (= no remat)
+    remat_policy: str = "nothing"
     # True: lax.scan over periods (fast compiles, small HLO).  False: unrolled
     # Python loop — used by the dry-run so cost_analysis counts every layer
     # (XLA prices a while-loop body ONCE, not × trip count).

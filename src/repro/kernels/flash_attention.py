@@ -15,6 +15,9 @@ TPU-native design (not a CUDA port):
 * The kernel also writes each row's log-sum-exp, so the custom VJP can run
   the flash backward (kernels.flash_vjp) from (q, k, v, out, lse) without
   differentiating through the pallas_call, which Mosaic cannot do.
+* The residuals out and lse carry the checkpoint names FLASH_OUT and
+  FLASH_LSE, so a remat policy can keep them (models.lm._remat) and the
+  backward never re-runs the kernel to rebuild them.
 
 Validated against kernels.ref.mha_ref with interpret=True in
 tests/test_kernels.py (CPU container; TPU is the lowering target).
@@ -27,12 +30,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import flash_vjp
 
 NEG_INF = -1e30
+# checkpoint names of the forward's residuals that only the kernel computes
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 
 
 def _fa_kernel(
@@ -217,6 +224,8 @@ def _flash_fwd_rule(q, k, v, causal, window, softcap, scale, block_q, block_k,
                     q_offset, interpret):
     out, lse = _flash_fwd(q, k, v, causal, window, softcap, scale, block_q,
                           block_k, q_offset, interpret)
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

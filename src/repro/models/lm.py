@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.core import tracepoints as tp
+from repro.kernels.flash_attention import FLASH_LSE, FLASH_OUT
 from repro.nn import attention as attn
 from repro.nn import core as nn
 from repro.nn import ffn as ffn_mod
@@ -280,7 +281,11 @@ def _remat(fn, policy: str):
         return jax.checkpoint(
             fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         )
-    return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
+    # "nothing": recompute the period body, but keep the Pallas flash forward's
+    # out and lse, which only a second run of the kernel could rebuild.
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(FLASH_OUT, FLASH_LSE)
+    )
 
 
 # ---------------------------------------------------------------------------
