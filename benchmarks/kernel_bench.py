@@ -64,15 +64,16 @@ def run(fast: bool = False) -> dict:
         "tpu_compute_us": round(flops2 / chip.peak_flops_bf16 * 1e6, 1),
     })
 
-    # gmm
-    E, C, Dm, F = 8, 256, 512, 1024
-    x = jax.random.normal(ks[0], (E, C, Dm), jnp.float32)
+    # gmm: ragged groups of uneven sizes over E experts (XLA's ragged_dot)
+    E, M, Dm, F = 8, 2048, 512, 1024
+    x = jax.random.normal(ks[0], (M, Dm), jnp.float32)
     w = jax.random.normal(ks[1], (E, Dm, F), jnp.float32)
-    g = jax.jit(ref.gmm_ref)
-    ms3 = _time(g, x, w, reps=5 if fast else 20)
-    flops3 = 2 * E * C * Dm * F
+    sizes = jnp.asarray([384, 128, 256, 0, 512, 256, 128, 384], jnp.int32)
+    g = jax.jit(ref.gmm_ragged)
+    ms3 = _time(g, x, w, sizes, reps=5 if fast else 20)
+    flops3 = 2 * M * Dm * F
     rows.append({
-        "kernel": "moe_gmm", "shape": f"E{E} C{C} D{Dm} F{F}",
+        "kernel": "moe_gmm", "shape": f"E{E} M{M} D{Dm} F{F}",
         "config": ops.active_config("moe_gmm", "ref"),
         "cpu_ms": round(ms3, 2), "flops": flops3,
         "tpu_compute_us": round(flops3 / chip.peak_flops_bf16 * 1e6, 1),

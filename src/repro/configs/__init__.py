@@ -12,31 +12,39 @@ from repro.configs.base import (
     RWKVConfig,
     ShapeConfig,
     reduced,
+    share,
     supports_shape,
 )
 
-_ARCH_MODULES = {
-    "gemma3-4b": "repro.configs.gemma3_4b",
-    "smollm-360m": "repro.configs.smollm_360m",
-    "gemma2-27b": "repro.configs.gemma2_27b",
-    "qwen2-0.5b": "repro.configs.qwen2_0_5b",
-    "chameleon-34b": "repro.configs.chameleon_34b",
-    "deepseek-moe-16b": "repro.configs.deepseek_moe_16b",
-    "dbrx-132b": "repro.configs.dbrx_132b",
-    "jamba-1.5-large": "repro.configs.jamba_1_5_large",
-    "musicgen-large": "repro.configs.musicgen_large",
-    "rwkv6-7b": "repro.configs.rwkv6_7b",
+# name -> (module, attribute): an architecture as published is its module's
+# ``CONFIG``; a share (``base.share``, one chip's part of a stated deployment)
+# is another attribute of the architecture's module
+_CONFIGS = {
+    "gemma3-4b": ("repro.configs.gemma3_4b", "CONFIG"),
+    "smollm-360m": ("repro.configs.smollm_360m", "CONFIG"),
+    "gemma2-27b": ("repro.configs.gemma2_27b", "CONFIG"),
+    "qwen2-0.5b": ("repro.configs.qwen2_0_5b", "CONFIG"),
+    "chameleon-34b": ("repro.configs.chameleon_34b", "CONFIG"),
+    "deepseek-moe-16b": ("repro.configs.deepseek_moe_16b", "CONFIG"),
+    "dbrx-132b": ("repro.configs.dbrx_132b", "CONFIG"),
+    "jamba-1.5-large": ("repro.configs.jamba_1_5_large", "CONFIG"),
+    "musicgen-large": ("repro.configs.musicgen_large", "CONFIG"),
+    "rwkv6-7b": ("repro.configs.rwkv6_7b", "CONFIG"),
+    "deepseek-moe-16b-ep4": ("repro.configs.deepseek_moe_16b", "EP4"),
 }
 
 
 def list_archs() -> list[str]:
-    return list(_ARCH_MODULES)
+    """The architectures as published (shares left out)."""
+    return [arch for arch, (_, attr) in _CONFIGS.items() if attr == "CONFIG"]
 
 
 def get_config(arch: str) -> ModelConfig:
-    if arch not in _ARCH_MODULES:
-        raise KeyError(f"unknown arch {arch!r}; known: {sorted(_ARCH_MODULES)}")
-    return importlib.import_module(_ARCH_MODULES[arch]).CONFIG
+    """An architecture as published, or one chip's share of one, by name."""
+    if arch not in _CONFIGS:
+        raise KeyError(f"unknown arch {arch!r}; known: {sorted(_CONFIGS)}")
+    module, attr = _CONFIGS[arch]
+    return getattr(importlib.import_module(module), attr)
 
 
 def get_shape(name: str) -> ShapeConfig:
@@ -55,5 +63,6 @@ __all__ = [
     "get_shape",
     "list_archs",
     "reduced",
+    "share",
     "supports_shape",
 ]

@@ -23,14 +23,22 @@ class LayerSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    n_experts: int = 8  # routed experts: the router's width
     top_k: int = 2
     n_shared: int = 0  # always-on shared experts (DeepSeekMoE)
     d_expert: int = 0  # per-expert FFN width (fine-grained experts)
-    capacity_factor: float = 1.25
-    router_aux_weight: float = 0.01
+    norm_topk: bool = True  # renormalise the top-k gates to sum to 1
+    router_aux_weight: float = 0.01  # sequence-level balance loss weight (alpha)
     router_z_weight: float = 1e-3
+    # the experts this chip holds, [held_first, held_first + n_held); 0 = all
+    held_first: int = 0
+    n_held: int = 0
     # jitter etc. omitted: deterministic routing for reproducibility
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first expert held here, number held)."""
+        return self.held_first, self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,6 +181,34 @@ def supports_shape(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
     return True, ""
 
 
+def share(
+    cfg: ModelConfig,
+    *,
+    name: str,
+    moe_layers: int,
+    experts: tuple[int, int],
+    vocab_size: int,
+) -> ModelConfig:
+    """One chip's share of an expert-parallel deployment, as one pipeline stage.
+
+    Keeps the leading dense layers and the ``moe_layers`` layers after them
+    (the others lie on further stages), holds ``experts`` = (first, count), a
+    contiguous block of every MoE layer's routed experts (the router still
+    scores all of them; the experts held elsewhere add nothing here), and a
+    vocabulary slice of ``vocab_size`` ids.  Every width is as published.
+    """
+    first, count = experts
+    if cfg.moe is None or not 0 <= first < first + count <= cfg.moe.n_experts:
+        raise ValueError(f"{cfg.name}: cannot hold experts {experts}")
+    return dataclasses.replace(
+        cfg,
+        name=name,
+        n_layers=cfg.first_k_dense + moe_layers,
+        vocab_size=vocab_size,
+        moe=dataclasses.replace(cfg.moe, held_first=first, n_held=count),
+    )
+
+
 def reduced(cfg: ModelConfig, *, layers: int | None = None) -> ModelConfig:
     """Smoke-test variant: same family/pattern, tiny dims, runs on 1 CPU."""
     n_layers = layers if layers is not None else max(cfg.first_k_dense + cfg.period, 2)
@@ -194,12 +230,16 @@ def reduced(cfg: ModelConfig, *, layers: int | None = None) -> ModelConfig:
         remat_policy="everything",
     )
     if cfg.moe is not None:
+        n = min(cfg.moe.n_experts, 8)
+        first, held = cfg.moe.held
         changes["moe"] = dataclasses.replace(
             cfg.moe,
-            n_experts=min(cfg.moe.n_experts, 8),
+            n_experts=n,
             top_k=min(cfg.moe.top_k, 2),
             n_shared=min(cfg.moe.n_shared, 1),
             d_expert=32 if cfg.moe.d_expert else 0,
+            held_first=first * n // cfg.moe.n_experts,
+            n_held=max(1, held * n // cfg.moe.n_experts) if cfg.moe.n_held else 0,
         )
     if cfg.mamba is not None:
         changes["mamba"] = dataclasses.replace(cfg.mamba, d_state=8, chunk=16)

@@ -21,7 +21,6 @@ CONFIG = ModelConfig(
         top_k=4,
         n_shared=0,
         d_expert=10752,
-        capacity_factor=1.25,
     ),
     rope_theta=500_000.0,
     tied_embeddings=False,

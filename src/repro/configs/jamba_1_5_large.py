@@ -33,7 +33,6 @@ CONFIG = ModelConfig(
         top_k=2,
         n_shared=0,
         d_expert=24576,
-        capacity_factor=1.25,
     ),
     mamba=MambaConfig(d_state=16, d_conv=4, expand=2),
     tied_embeddings=False,

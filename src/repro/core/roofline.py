@@ -212,7 +212,7 @@ def count_params(abs_params: Any, *, active: bool, cfg: ModelConfig) -> int:
     for path, leaf in flat:
         n = int(np.prod(leaf.shape, dtype=np.int64)) if leaf.shape else 1
         keys = "/".join(str(getattr(p, "key", getattr(p, "name", p))) for p in path)
-        if active and cfg.moe and "/ffn/w" in keys and leaf.ndim >= 3 and leaf.shape[-3] == cfg.moe.n_experts:
+        if active and cfg.moe and "/ffn/w" in keys and leaf.ndim >= 3 and leaf.shape[-3] == cfg.moe.held[1]:
             n = n * cfg.moe.top_k / cfg.moe.n_experts
         total += n
     return int(total)

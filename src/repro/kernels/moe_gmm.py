@@ -1,89 +1,110 @@
-"""Grouped expert matmul (GMM) Pallas TPU kernel for MoE layers.
+"""Ragged grouped expert matmul (GMM) for dropless MoE layers, on TPU Pallas.
 
-(E, C, D) @ (E, D, F) -> (E, C, F): one matmul per expert over its capacity
-bucket.  TPU-native choices:
-* The grid is (E, C/bc, F/bf, D/bd) with the contraction dim innermost, so a
-  (bc, bf) f32 accumulator persists in VMEM scratch across the D sweep and the
-  MXU sees back-to-back (bc×bd)·(bd×bf) tiles — bc/bf/bd default to 128/128/512
-  (multiples of the 128-lane MXU edge).
-* Expert weight tiles stream HBM→VMEM once per (ci, fi) pair; because experts
-  are the outermost grid dim, weights for expert e are fully reused across its
-  capacity rows before moving on (maximises VMEM reuse of the big operand).
-* An optional fused epilogue applies the gated-FFN activation, saving one HBM
-  round-trip of the (E, C, F) intermediate in the w1/w3 pass.
+    gmm(x (M, K), w (G, K, N), group_sizes (G,)) -> (M, N)
 
-Validated against kernels.ref.gmm_ref with interpret=True.
+Rows are sorted by group: rows ``[off_g, off_g + group_sizes[g])`` are
+multiplied by ``w[g]``, with f32 accumulation.  Rows past
+``sum(group_sizes)`` (the pairs routed to experts held elsewhere, and
+padding) come out zero, and so do their gradients.
+
+The three products are megablox's Pallas kernels (``gmm`` and ``tgmm`` of
+``jax.experimental.pallas.ops.tpu.megablox``): the grid walks only the row
+tiles that hold a group (the tile-to-group map is scalar-prefetched), so
+the work follows the routed rows and not a capacity.  Each product is
+called from a jit of its own, which names its HLO op, and so its event in a
+profile's XLA Ops line:
+
+* ``moe_gmm_fwd``: ``x @ w[g]``;
+* ``moe_gmm_dx``: ``dy @ w[g]^T``, the input gradient;
+* ``moe_gmm_dw``: ``x_g^T @ dy_g`` for each group, the weight gradient.
+
+Rows past the groups form one more group that has no weight: megablox then
+computes only the weighted groups and zeroes every row outside them.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import importlib
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+# the package re-exports megablox's custom-VJP ``gmm`` under the module's name
+_mb = importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+ROW_TILE = 512
 
 
-def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr, *, n_d_blocks: int, epilogue: Optional[str]):
-    di = pl.program_id(3)
-
-    @pl.when(di == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    x = x_ref[0]  # (bc, bd)
-    w = w_ref[0]  # (bd, bf)
-    acc_scr[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(di == n_d_blocks - 1)
-    def _finish():
-        acc = acc_scr[...]
-        if epilogue == "silu":
-            acc = acc * jax.nn.sigmoid(acc)
-        elif epilogue == "gelu":
-            acc = jax.nn.gelu(acc, approximate=True)
-        o_ref[0, ...] = acc.astype(o_ref.dtype)
+def _tile(dim: int) -> int:
+    return 512 if dim % 512 == 0 else dim
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_c", "block_f", "block_d", "epilogue", "interpret")
-)
-def gmm(
-    x: jax.Array,
-    w: jax.Array,
-    *,
-    block_c: int = 128,
-    block_f: int = 128,
-    block_d: int = 512,
-    epilogue: Optional[str] = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """x: (E, C, D); w: (E, D, F) -> (E, C, F) with f32 accumulation."""
-    E, C, D = x.shape
-    _, _, F = w.shape
-    block_c = min(block_c, C)
-    block_f = min(block_f, F)
-    block_d = min(block_d, D)
-    pad_c, pad_f, pad_d = -C % block_c, -F % block_f, -D % block_d
-    if pad_c or pad_d:
-        x = jnp.pad(x, ((0, 0), (0, pad_c), (0, pad_d)))
-    if pad_d or pad_f:
-        w = jnp.pad(w, ((0, 0), (0, pad_d), (0, pad_f)))
-    n_c, n_f, n_d = (C + pad_c) // block_c, (F + pad_f) // block_f, (D + pad_d) // block_d
+def _tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    return min(ROW_TILE, m), _tile(k), _tile(n)
 
-    out = pl.pallas_call(
-        functools.partial(_gmm_kernel, n_d_blocks=n_d, epilogue=epilogue),
-        grid=(E, n_c, n_f, n_d),
-        in_specs=[
-            pl.BlockSpec((1, block_c, block_d), lambda e, ci, fi, di: (e, ci, di)),
-            pl.BlockSpec((1, block_d, block_f), lambda e, ci, fi, di: (e, di, fi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_c, block_f), lambda e, ci, fi, di: (e, ci, fi)),
-        out_shape=jax.ShapeDtypeStruct((E, n_c * block_c, n_f * block_f), x.dtype),
-        scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        interpret=interpret,
-    )(x, w)
-    return out[:, :C, :F]
+
+def _sizes(group_sizes: jax.Array, m: int) -> jax.Array:
+    """The groups, then one group of the rows past them."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    return jnp.concatenate([group_sizes, (m - group_sizes.sum())[None]])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_gmm_fwd(x, w, sizes, *, interpret: bool = False):
+    """x @ w[g] per group; rows outside the weighted groups are 0."""
+    return _mb.gmm.__wrapped__(x, w, sizes, x.dtype,
+                               _tiling(x.shape[0], x.shape[1], w.shape[2]),
+                               interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_gmm_dx(dy, w, sizes, *, interpret: bool = False):
+    """dy @ w[g]^T per group: the gradient of ``moe_gmm_fwd``'s input."""
+    return _mb.gmm.__wrapped__(dy, w, sizes, dy.dtype,
+                               _tiling(dy.shape[0], dy.shape[1], w.shape[1]),
+                               transpose_rhs=True, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_gmm_dw(x, dy, sizes, *, interpret: bool = False):
+    """x_g^T @ dy_g per weighted group: the gradient of ``moe_gmm_fwd``'s weight."""
+    groups = sizes.shape[0] - 1
+    return _mb.tgmm.__wrapped__(x.swapaxes(0, 1), dy, sizes, x.dtype,
+                                _tiling(x.shape[0], x.shape[1], dy.shape[1]),
+                                num_actual_groups=groups, interpret=interpret)
+
+
+def _padded(a: jax.Array, m: int) -> jax.Array:
+    return jnp.pad(a, ((0, m - a.shape[0]), (0, 0))) if m != a.shape[0] else a
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm(x, w, group_sizes, interpret):
+    return _gmm_fwd(x, w, group_sizes, interpret)[0]
+
+
+def _gmm_fwd(x, w, group_sizes, interpret):
+    M = x.shape[0]
+    m = -(-M // ROW_TILE) * ROW_TILE if M > ROW_TILE else M
+    sizes = _sizes(group_sizes, m)
+    xp = _padded(x, m)
+    out = moe_gmm_fwd(xp, w, sizes, interpret=interpret)[:M]
+    return out, (xp, w, sizes)
+
+
+def _gmm_bwd(interpret, res, dy):
+    xp, w, sizes = res
+    M = dy.shape[0]
+    dyp = _padded(dy.astype(xp.dtype), xp.shape[0])
+    dx = moe_gmm_dx(dyp, w, sizes, interpret=interpret)[:M]
+    dw = moe_gmm_dw(xp, dyp, sizes, interpret=interpret)
+    return dx, dw.astype(w.dtype), None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
+        interpret: bool = False) -> jax.Array:
+    """x: (M, K) sorted by group; w: (G, K, N); group_sizes: (G,) -> (M, N)."""
+    return _gmm(x, w, group_sizes, interpret)

@@ -13,7 +13,7 @@ from __future__ import annotations
 import functools
 import os
 from contextlib import contextmanager
-from typing import Any, Iterator, Mapping, Optional
+from typing import Any, Callable, Iterator, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -285,13 +285,16 @@ def decode_attention_seq_sharded(
     )(q, k_cache, v_cache, pos_ids, cur_pos)
 
 
-def gmm(x: jax.Array, w: jax.Array, *, impl: Optional[str] = None) -> jax.Array:
+def gmm(
+    x: jax.Array, w: jax.Array, group_sizes: jax.Array, *, impl: Optional[str] = None
+) -> jax.Array:
+    """Ragged grouped matmul: x (M, K) sorted by group, w (G, K, N) -> (M, N)."""
     impl = _resolve(impl)
     if impl == "pallas":
-        return _gmm_pallas(
-            x, w, interpret=_interp(), **tuned_overrides("moe_gmm", "pallas")
-        )
-    return _ref.gmm_ref(x, w)
+        return _gmm_pallas(x, w, group_sizes, interpret=_interp())
+    if impl == "ref":
+        return _ref.gmm_ref(x, w, group_sizes)
+    return _ref.gmm_ragged(x, w, group_sizes)
 
 
 def moe_ffn(
@@ -299,18 +302,18 @@ def moe_ffn(
     w1: jax.Array,
     w3: jax.Array,
     w2: jax.Array,
+    group_sizes: jax.Array,
     *,
-    act: str = "silu",
+    act: Callable[[jax.Array], jax.Array] = jax.nn.silu,
     impl: Optional[str] = None,
 ) -> jax.Array:
-    """Per-expert gated FFN over capacity buckets: act(x@w1) * (x@w3) @ w2."""
-    impl = _resolve(impl)
-    if impl == "pallas":
-        tuned = tuned_overrides("moe_gmm", "pallas")
-        h = _gmm_pallas(x, w1, epilogue=act, interpret=_interp(), **tuned)
-        h = h * _gmm_pallas(x, w3, interpret=_interp(), **tuned)
-        return _gmm_pallas(h, w2, interpret=_interp(), **tuned)
-    return _ref.moe_ffn_ref(x, w1, w3, w2, act=act)
+    """Per-expert gated FFN over rows sorted by expert: act(x@w1) * (x@w3) @ w2.
+
+    Rows past ``sum(group_sizes)`` come out zero.
+    """
+    mm = functools.partial(gmm, group_sizes=group_sizes, impl=impl)
+    h = act(mm(x, w1).astype(jnp.float32)) * mm(x, w3).astype(jnp.float32)
+    return mm(h.astype(x.dtype), w2)
 
 
 def rwkv6_scan(

@@ -13,7 +13,7 @@ Two tiers per op:
 Shape conventions:
   attention   q: (B, Sq, Hq, D);  k, v: (B, Skv, Hkv, D);  Hq % Hkv == 0
   decode      q: (B, Hq, D);      cache: (B, S, Hkv, D);   pos_ids: (B, S)
-  gmm         x: (E, C, D);       w: (E, D, F)
+  gmm         x: (M, D) sorted by group;  w: (G, D, F);  group_sizes: (G,)
   rwkv6       r,k,v,w: (B, T, H, K);  u: (H, K);  state: (B, H, K, V)
   mamba       x,dt: (B, T, DI);   B,C: (B, T, N);  A: (DI, N);  state: (B, DI, N)
 """
@@ -237,26 +237,24 @@ def decode_attention_ref(
 # ---------------------------------------------------------------------------
 
 
-def gmm_ref(x: jax.Array, w: jax.Array) -> jax.Array:
-    """(E, C, D) @ (E, D, F) -> (E, C, F), f32 accumulation."""
-    return jax.lax.dot_general(
-        x,
-        w,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+def gmm_ref(x: jax.Array, w: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """Rows of group g times w[g], f32 accumulation; rows past the groups are 0.
+
+    Every row against every group's weight, masked to its own: G times the
+    work, for test-scale oracles only.
+    """
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(x.shape[0])[:, None]
+    member = ((row >= ends - group_sizes) & (row < ends)).astype(jnp.float32)  # (M, G)
+    out = jnp.einsum("md,gdf,mg->mf", x.astype(jnp.float32), w.astype(jnp.float32), member)
+    return out.astype(x.dtype)
+
+
+def gmm_ragged(x: jax.Array, w: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """The production jnp path of the grouped matmul: XLA's ``ragged_dot``."""
+    return jax.lax.ragged_dot(
+        x, w, group_sizes.astype(jnp.int32), preferred_element_type=jnp.float32
     ).astype(x.dtype)
-
-
-def moe_ffn_ref(
-    x: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array, act: str = "silu"
-) -> jax.Array:
-    """Per-expert gated FFN: act(x@w1) * (x@w3) @ w2."""
-    from repro.nn.core import ACTIVATIONS
-
-    h = ACTIVATIONS[act](gmm_ref(x, w1).astype(jnp.float32)) * gmm_ref(x, w3).astype(
-        jnp.float32
-    )
-    return gmm_ref(h.astype(x.dtype), w2)
 
 
 # ---------------------------------------------------------------------------

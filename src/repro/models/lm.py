@@ -224,6 +224,21 @@ def cache_axes(cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _aux_zero() -> dict:
+    """What a block adds to the step's metrics: its auxiliary loss, and the
+    MoE layer's routing counters (pairs computed on held experts, summed over
+    layers; the fullest held expert's rows, the max over layers)."""
+    return {"loss": jnp.zeros((), jnp.float32),
+            "moe_rows": jnp.zeros((), jnp.int32),
+            "moe_max_rows": jnp.zeros((), jnp.int32)}
+
+
+def _aux_add(a: dict, b: dict) -> dict:
+    return {"loss": a["loss"] + b["loss"],
+            "moe_rows": a["moe_rows"] + b["moe_rows"],
+            "moe_max_rows": jnp.maximum(a["moe_max_rows"], b["moe_max_rows"])}
+
+
 def _block_apply(
     p: dict,
     x: jax.Array,
@@ -233,9 +248,9 @@ def _block_apply(
     *,
     mode: str,
     cache: Optional[dict],
-) -> tuple[jax.Array, jax.Array, Optional[dict]]:
-    """Returns (x, aux_loss_scalar, new_cache)."""
-    aux = jnp.zeros((), jnp.float32)
+) -> tuple[jax.Array, dict, Optional[dict]]:
+    """Returns (x, aux, new_cache); aux as ``_aux_zero`` gives it."""
+    aux = _aux_zero()
     new_cache: dict = {}
     h = nn.rmsnorm(p["norm1"], x, cfg.norm_eps)
     mixer_cache = cache.get("mixer") if cache else None
@@ -263,7 +278,11 @@ def _block_apply(
                 h = ffn_mod.ffn_apply(p["ffn"], h, cfg)
             elif spec.ffn == "moe":
                 h, moe_aux = ffn_mod.moe_apply(p["ffn"], h, cfg)
-                aux = aux + moe_aux["moe_load_balance"] + moe_aux["moe_z_loss"]
+                aux = {
+                    "loss": moe_aux["moe_load_balance"] + moe_aux["moe_z_loss"],
+                    "moe_rows": moe_aux["moe_rows"],
+                    "moe_max_rows": moe_aux["moe_max_rows"],
+                }
             elif spec.ffn == "rwkv_ffn":
                 h, fc = rwkv_mod.channel_mix_apply(p["ffn"], h, cfg, cache=ffn_cache)
                 if fc is not None:
@@ -288,6 +307,15 @@ def _remat(fn, policy: str):
     )
 
 
+def _unscanned_apply(p, x, cfg, spec, positions, mode, cache):
+    """A layer outside the scanned periods, under the same remat policy."""
+
+    def block(p, x, cache):
+        return _block_apply(p, x, cfg, spec, positions, mode=mode, cache=cache)
+
+    return _remat(block, cfg.remat_policy)(p, x, cache)
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -302,8 +330,8 @@ def forward(
     *,
     mode: str = "full",
     caches: Optional[dict] = None,
-) -> tuple[jax.Array, jax.Array, Optional[dict]]:
-    """tokens: (B, S) -> (hidden (B, S, D), aux_loss, new_caches)."""
+) -> tuple[jax.Array, dict, Optional[dict]]:
+    """tokens: (B, S) -> (hidden (B, S, D), aux (``_aux_zero``), new_caches)."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -315,7 +343,7 @@ def forward(
                 params["frontend"], frontend_embed.astype(x.dtype)
             )
     tp.point("lm.embed_out", x)
-    aux = jnp.zeros((), jnp.float32)
+    aux = _aux_zero()
     new_caches: dict = {}
 
     # head layers (unscanned)
@@ -324,11 +352,10 @@ def forward(
         if not name.startswith("head"):
             continue
         with jax.named_scope(name):
-            x, a, c = _block_apply(
-                params[name], x, cfg, spec, positions, mode=mode,
-                cache=(caches or {}).get(name),
+            x, a, c = _unscanned_apply(
+                params[name], x, cfg, spec, positions, mode, (caches or {}).get(name)
             )
-        aux = aux + a
+        aux = _aux_add(aux, a)
         if c is not None:
             new_caches[name] = c
 
@@ -348,7 +375,7 @@ def forward(
                         pp[f"pos{pos}"], x, cfg, spec, positions, mode=mode,
                         cache=pc[f"pos{pos}"] if pc is not None else None,
                     )
-                aux = aux + a
+                aux = _aux_add(aux, a)
                 if c is not None:
                     out_caches[f"pos{pos}"] = c
             return (x, aux), (out_caches if want_cache else None)
@@ -378,11 +405,10 @@ def forward(
         if not name.startswith("tail"):
             continue
         with jax.named_scope(name):
-            x, a, c = _block_apply(
-                params[name], x, cfg, spec, positions, mode=mode,
-                cache=(caches or {}).get(name),
+            x, a, c = _unscanned_apply(
+                params[name], x, cfg, spec, positions, mode, (caches or {}).get(name)
             )
-        aux = aux + a
+        aux = _aux_add(aux, a)
         if c is not None:
             new_caches[name] = c
 
@@ -447,9 +473,12 @@ def loss_fn(
     )
     ce = nll_sum / T
     z = z_sum / T
-    loss = ce + z + aux
+    loss = ce + z + aux["loss"]
     tp.point("lm.loss", loss)
-    return loss, {"ce": ce, "z_loss": z, "aux": aux, "tokens": jnp.float32(T)}
+    metrics = {"ce": ce, "z_loss": z, "aux": aux["loss"], "tokens": jnp.float32(T)}
+    if cfg.moe is not None:
+        metrics.update(moe_rows=aux["moe_rows"], moe_max_rows=aux["moe_max_rows"])
+    return loss, metrics
 
 
 # ---------------------------------------------------------------------------

@@ -1,24 +1,30 @@
-"""FFN blocks: dense gated-GLU and GShard-style MoE with capacity routing.
+"""FFN blocks: dense gated-GLU and a dropless MoE over the experts held here.
 
-MoE is TPU-idiomatic (MXU-friendly dense dispatch, not GPU scatter-gather):
-tokens are grouped, each group routes top-k into per-expert capacity buckets
-via one-hot dispatch/combine einsums, and the expert compute itself is a
-grouped matmul (kernels.ops.moe_ffn / the moe_gmm Pallas kernel).  Experts are
-sharded over the 'model' mesh axis (expert parallelism); GSPMD materialises
-the token all-to-all from the dispatch einsum's shardings.
+The MoE layer is told which experts it holds (``MoEConfig.held``: a
+contiguous block, by default all of them).  It routes every token over all
+``n_experts`` (softmax, top-k, renormalised where the config says), sorts
+the (token, choice) pairs that fall on its experts into ragged groups, one
+per expert, computes them with a grouped matmul (kernels.ops.moe_ffn: the
+megablox Pallas kernels on TPU), and adds each result back to its token
+scaled by its gate.  Pairs routed to experts held elsewhere add nothing
+here: under expert parallelism the chips that hold them add their part.
+Nothing is dropped, so there is no capacity.  Shared experts are computed by
+every chip alike.
 
-Aux losses (load-balance + router z-loss) are returned functionally and
-accumulated through the layer scan.
+The auxiliary losses (a sequence-level load balance over all experts'
+probabilities and counts, and the router z-loss) and the routing counters
+(``moe_rows``: pairs computed here; ``moe_max_rows``: the fullest held
+expert's rows) are returned functionally and carried through the layer scan.
 """
 from __future__ import annotations
 
-import math
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, MoEConfig
+from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.nn import core as nn
 
@@ -51,15 +57,22 @@ def ffn_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 # MoE
 # ---------------------------------------------------------------------------
 
+# (token, choice) pairs dispatched at once: a layer's tokens go through the
+# routed experts in chunks of at most this many pairs, each chunk recomputed
+# in the backward, which bounds the sorted buffers (a chunk's T*K rows, as
+# many as all its pairs could need) whatever the batch
+DISPATCH_ROWS = 1 << 16
+
 
 def moe_init(pf: nn.ParamFactory, cfg: ModelConfig) -> dict:
     assert cfg.moe is not None
     m = cfg.moe
-    D, E, F = cfg.d_model, m.n_experts, m.d_expert or cfg.d_ff
+    D, F = cfg.d_model, m.d_expert or cfg.d_ff
+    _, E = m.held
     out_scale = 0.02 / max(1, 2 * cfg.n_layers) ** 0.5
     p = {
         "router": nn.linear_init(
-            pf, "router", (D,), (E,), ("embed",), ("experts",), scale=0.02
+            pf, "router", (D,), (m.n_experts,), ("embed",), ("experts",), scale=0.02
         ),
         "w1": pf.param("w1", (E, D, F), ("experts", "embed", "expert_mlp")),
         "w3": pf.param("w3", (E, D, F), ("experts", "embed", "expert_mlp")),
@@ -72,78 +85,113 @@ def moe_init(pf: nn.ParamFactory, cfg: ModelConfig) -> dict:
     return p
 
 
-def _capacity(group: int, m: MoEConfig) -> int:
-    c = math.ceil(group * m.top_k * m.capacity_factor / m.n_experts)
-    return max(8, -(-c // 8) * 8)  # pad to sublane multiple
+@jax.custom_vjp
+def _combine(out: jax.Array, order: jax.Array, slot: jax.Array, w: jax.Array) -> jax.Array:
+    """y[t] = sum_k w[t, k] * out[slot[t, k]], in f32.
 
-
-def pick_group_size(n_tokens: int, target: int = 2048) -> int:
-    """Largest divisor of n_tokens that is <= target (prefer big groups)."""
-    g = min(n_tokens, target)
-    while n_tokens % g:
-        g -= 1
-    return g
-
-
-def moe_apply(
-    p: dict, x: jax.Array, cfg: ModelConfig, *, group_size: Optional[int] = None
-) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """x: (B, S, D) -> (y, aux_losses).
-
-    GShard top-k capacity routing with deterministic (position-priority)
-    overflow dropping; gates renormalised over the kept assignments.
+    out: (T*K, D) expert rows in ``order`` (the pairs, sorted); slot: (T, K)
+    each pair's row; w: (T, K) gates.  Neither pass forms a (T, K, D) array:
+    the forward adds one choice at a time, the backward works on the sorted
+    rows.
     """
+    y = 0.0
+    for k in range(slot.shape[1]):
+        y = y + out.at[slot[:, k]].get(unique_indices=True).astype(jnp.float32) * w[:, k, None]
+    return y
+
+
+def _combine_fwd(out, order, slot, w):
+    return _combine(out, order, slot, w), (out, order, slot, w)
+
+
+def _combine_bwd(res, dy):
+    out, order, slot, w = res
+    dy_rows = dy.astype(out.dtype)[order // slot.shape[1]]  # each row's token's dy
+    gate = w.reshape(-1)[order].astype(out.dtype)
+    d_gate = jnp.sum(out.astype(jnp.float32) * dy_rows.astype(jnp.float32), axis=-1)
+    return dy_rows * gate[:, None], None, None, d_gate[slot]
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dispatch_chunks(T: int, K: int) -> int:
+    """How many chunks a layer's T tokens are dispatched in: the fewest that
+    divide T and hold at most ``DISPATCH_ROWS`` (token, choice) pairs each."""
+    n = -(-T * K // DISPATCH_ROWS)
+    while T % n:
+        n += 1
+    return n
+
+
+def _routed(p, chunk, *, first: int, n_held: int, act: str):
+    """(y (T, D), rows per held expert) of one chunk's routed experts:
+    ``chunk`` is its tokens (T, D), their gates and their choices (T, K)."""
+    xt, gates, idx = chunk
+    T, K = idx.shape
+    with jax.named_scope("moe.dispatch"):
+        local = idx.reshape(-1) - first  # (T*K,) the held expert of each pair
+        held = (local >= 0) & (local < n_held)
+        group = jnp.where(held, local, n_held)  # absent experts sort last
+        order = jnp.argsort(group, stable=True)  # pairs, grouped by expert
+        sizes = jnp.zeros((n_held + 1,), jnp.int32).at[group].add(1)[:n_held]
+        rows = xt[order // K]  # (T*K, D)
+
+    with jax.named_scope("moe.experts"):
+        out = ops.moe_ffn(
+            rows, p["w1"], p["w3"], p["w2"], sizes, act=nn.ACTIVATIONS[act]
+        )
+
+    with jax.named_scope("moe.combine"):
+        slot = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * K, dtype=order.dtype), unique_indices=True
+        )  # each pair's row in ``out``
+        w = jnp.where(held, gates.reshape(-1), 0.0).reshape(T, K)
+        y = _combine(out, order, slot.reshape(T, K), w).astype(xt.dtype)
+    return y, sizes
+
+
+def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, dict]:
+    """x: (B, S, D) -> (y, aux): the losses ``moe_load_balance`` and
+    ``moe_z_loss`` and the counters ``moe_rows`` and ``moe_max_rows``."""
     assert cfg.moe is not None
     m = cfg.moe
     B, S, D = x.shape
-    E = m.n_experts
-    T = B * S
-    G = group_size or pick_group_size(T)
-    n_g = T // G
-    C = _capacity(G, m)
-    xg = x.reshape(n_g, G, D)
+    T, E, K = B * S, m.n_experts, m.top_k
+    first, n_held = m.held
+    xt = x.reshape(T, D)
 
-    logits = nn.linear(p["router"], xg).astype(jnp.float32)  # (n_g, G, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, m.top_k)  # (n_g, G, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.route"):
+        logits = jax.lax.dot_general(
+            xt, p["router"]["w"], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, K)  # (T, K)
+        if m.norm_topk:
+            gates = gates / gates.sum(-1, keepdims=True)
+        # per sequence: alpha * sum_e f_e P_e, f_e = E/(S K) * the row's pairs
+        # on e, P_e = the row's mean probability of e; then the mean over rows
+        counts = jax.nn.one_hot(idx, E, dtype=jnp.float32).reshape(B, S * K, E).sum(1)
+        f = counts * (E / (S * K))
+        balance = jnp.mean(jnp.sum(f * probs.reshape(B, S, E).mean(1), axis=-1))
+        z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
 
-    # Position-in-expert, priority = (choice rank, token position).
-    dispatch = jnp.zeros((n_g, G, E, C), x.dtype)
-    combine = jnp.zeros((n_g, G, E, C), jnp.float32)
-    counts = jnp.zeros((n_g, E), jnp.int32)
-    for kk in range(m.top_k):
-        e_k = idx[:, :, kk]  # (n_g, G)
-        onehot_e = jax.nn.one_hot(e_k, E, dtype=jnp.int32)  # (n_g, G, E)
-        pos_k = counts[:, None, :] + jnp.cumsum(onehot_e, axis=1) - onehot_e
-        pos_in_e = jnp.take_along_axis(pos_k, e_k[..., None], axis=2)[..., 0]  # (n_g, G)
-        keep = pos_in_e < C
-        counts = counts + onehot_e.sum(axis=1)
-        oh_ec = jax.nn.one_hot(e_k, E)[..., None] * jax.nn.one_hot(
-            jnp.where(keep, pos_in_e, C), C + 1
-        )[..., None, :-1]  # (n_g, G, E, C); overflow row C sliced off
-        dispatch = dispatch + oh_ec.astype(x.dtype)
-        combine = combine + oh_ec * (gates[:, :, kk] * keep)[..., None, None]
-
-    # Dense dispatch: (n_g, G, E, C) x (n_g, G, D) -> (E, n_g*C, D)
-    expert_in = jnp.einsum("gtec,gtd->gecd", dispatch, x.reshape(n_g, G, D))
-    expert_in = expert_in.transpose(1, 0, 2, 3).reshape(E, n_g * C, D)
-    expert_out = ops.moe_ffn(expert_in, p["w1"], p["w3"], p["w2"], act=cfg.act)
-    expert_out = expert_out.reshape(E, n_g, C, D).transpose(1, 0, 2, 3)  # (n_g,E,C,D)
-    y = jnp.einsum(
-        "gtec,gecd->gtd", combine.astype(jnp.float32), expert_out.astype(jnp.float32)
-    )
-    y = y.reshape(B, S, D).astype(x.dtype)
+    n = dispatch_chunks(T, K)
+    routed = jax.checkpoint(partial(_routed, p, first=first, n_held=n_held, act=cfg.act))
+    y, sizes = jax.lax.map(routed, (xt.reshape(n, T // n, D), gates.reshape(n, T // n, K),
+                                    idx.reshape(n, T // n, K)))
+    y = y.reshape(B, S, D)
+    sizes = sizes.sum(0)
 
     if "shared" in p:
-        y = y + ffn_apply(p["shared"], x, cfg)
+        with jax.named_scope("moe.shared"):
+            y = y + ffn_apply(p["shared"], x, cfg)
 
-    # Aux losses (Switch/GShard load-balance + z-loss), f32.
-    me = probs.mean(axis=(0, 1))  # (E,) mean router prob
-    ce = (dispatch.sum(axis=(1, 3)) / G).mean(axis=0).astype(jnp.float32)  # frac routed
     aux = {
-        "moe_load_balance": E * jnp.sum(me * ce) * m.router_aux_weight,
-        "moe_z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
-        * m.router_z_weight,
+        "moe_load_balance": balance * m.router_aux_weight,
+        "moe_z_loss": z * m.router_z_weight,
+        "moe_rows": sizes.sum(),
+        "moe_max_rows": sizes.max(),
     }
     return y, aux

@@ -85,23 +85,29 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig):
             mb_batch = jax.tree.map(split, {"t": tokens, "l": labels, "f": fe})
 
             def body(carry, xs):
-                acc, loss_sum = carry
-                (loss, _m), g = grad_fn(params, xs["t"], xs["l"], xs.get("f"))
+                acc, loss_sum, rows, max_rows = carry
+                (loss, m), g = grad_fn(params, xs["t"], xs["l"], xs.get("f"))
                 acc = jax.tree.map(
                     lambda a, gi: a + gi.astype(acc_dtype), acc, g
                 )
-                return (acc, loss_sum + loss), None
+                if cfg.moe is not None:
+                    rows = rows + m["moe_rows"]
+                    max_rows = jnp.maximum(max_rows, m["moe_max_rows"])
+                return (acc, loss_sum + loss, rows, max_rows), None
 
             zero = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, acc_dtype), params
             )
-            (grads, loss_sum), _ = jax.lax.scan(
-                body, (zero, jnp.zeros((), jnp.float32)), mb_batch
+            count = jnp.zeros((), jnp.int32)
+            (grads, loss_sum, rows, max_rows), _ = jax.lax.scan(
+                body, (zero, jnp.zeros((), jnp.float32), count, count), mb_batch
             )
             grads = jax.tree.map(lambda g: (g / n_micro).astype(jnp.float32), grads)
             loss = loss_sum / n_micro
             metrics = {"ce": loss, "z_loss": jnp.zeros(()), "aux": jnp.zeros(()),
                        "tokens": jnp.float32(tokens.size)}
+            if cfg.moe is not None:
+                metrics.update(moe_rows=rows, moe_max_rows=max_rows)
 
         new_params, new_opt, opt_metrics = optim.adamw_update(
             params, grads, state["opt"], opt_cfg

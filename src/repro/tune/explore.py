@@ -102,18 +102,6 @@ def _run_decode(space: KernelSpace, impl: str) -> Callable[[], Any]:
     return lambda: fn(q, k_cache, v_cache, pos_ids, cur_pos)
 
 
-def _run_gmm(space: KernelSpace, impl: str) -> Callable[[], Any]:
-    import jax
-
-    from repro.kernels import ops
-
-    w = space.workload
-    x = _arr((w["E"], w["C"], w["D"]), 1)
-    wt = _arr((w["E"], w["D"], w["F"]), 2)
-    fn = jax.jit(lambda a, b: ops.gmm(a, b, impl=impl))
-    return lambda: fn(x, wt)
-
-
 def _run_rwkv6(space: KernelSpace, impl: str) -> Callable[[], Any]:
     import jax
 
@@ -150,7 +138,6 @@ def _run_mamba(space: KernelSpace, impl: str) -> Callable[[], Any]:
 _RUNNERS: dict[str, Callable[[KernelSpace, str], Callable[[], Any]]] = {
     "flash_attention": _run_flash,
     "decode_attention": _run_decode,
-    "moe_gmm": _run_gmm,
     "rwkv6_scan": _run_rwkv6,
     "mamba_scan": _run_mamba,
 }

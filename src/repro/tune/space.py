@@ -211,23 +211,6 @@ def _decode_tiles(p: Mapping[str, int], w: Mapping[str, int]):
     return tiles, scratch
 
 
-def _gmm_cost(p: Mapping[str, int], w: Mapping[str, int]):
-    E, C, D, Fdim = w["E"], w["C"], w["D"], w["F"]
-    bc, bf, bd = p["block_c"], p["block_f"], p["block_d"]
-    c_eff, f_eff, d_eff = _pad(C, bc), _pad(Fdim, bf), _pad(D, bd)
-    flops = 2.0 * E * c_eff * d_eff * f_eff
-    hbm = F32 * E * (C * D + D * Fdim + C * Fdim)
-    launches = E * math.ceil(C / bc) * math.ceil(Fdim / bf) * math.ceil(D / bd)
-    return flops, hbm, launches
-
-
-def _gmm_tiles(p: Mapping[str, int], w: Mapping[str, int]):
-    bc, bf, bd = p["block_c"], p["block_f"], p["block_d"]
-    tiles = [((bc, bd), F32), ((bd, bf), F32), ((bc, bf), F32)]
-    scratch = [((bc, bf), F32)]  # f32 accumulator
-    return tiles, scratch
-
-
 def _scan_cost(state_cols: str):
     """Chunked linear-scan cost: within-chunk pairwise work is O(T·L), the
     chunk loop costs one launch per T/L iterations — the classic small-chunk
@@ -302,18 +285,6 @@ def default_spaces() -> dict[str, KernelSpace]:
                      ("float32", (4, 1024, 4, 64)), ("int32", (4, 1024)),
                      ("int32", (4,))),
             cost=_decode_cost, tiles=_decode_tiles,
-        ),
-        KernelSpace(
-            op="moe_gmm", backend="pallas", impl="pallas",
-            grid={"block_c": (128, 256), "block_f": (128, 256),
-                  "block_d": (128, 256)},
-            defaults={"block_c": 128, "block_f": 128, "block_d": 256},
-            align={"block_c": MXU_ALIGN, "block_f": MXU_ALIGN,
-                   "block_d": MXU_ALIGN},
-            divides={"block_c": "C", "block_f": "F", "block_d": "D"},
-            workload={"E": 4, "C": 256, "D": 256, "F": 256},
-            sig=_sig(("float32", (4, 256, 256)), ("float32", (4, 256, 256))),
-            cost=_gmm_cost, tiles=_gmm_tiles,
         ),
         KernelSpace(
             op="rwkv6_scan", backend="chunked", impl="chunked",

@@ -108,12 +108,20 @@ def test_decode_attention_compiles(one_chip, window):
 
 
 def test_gmm_compiles(one_chip):
-    E, C, D, F = 16, 256, 2048, 1408  # deepseek-moe expert widths
+    """The three grouped-matmul products at deepseek-moe's expert widths."""
+    M, E, D, F = 49152, 16, 2048, 1408  # one dispatch chunk's rows, experts held
+
+    def loss(x, w, sizes):
+        return gmm(x, w, sizes).astype(F32).sum()
+
     text = _compile_text(
-        lambda x, w: gmm(x, w, epilogue="silu"),
-        [((E, C, D), BF16), ((E, D, F), BF16)],
+        jax.value_and_grad(loss, argnums=(0, 1)),
+        [((M, D), BF16), ((E, D, F), BF16), ((E,), jnp.int32)],
         one_chip,
     )
+    for product in ("moe_gmm_fwd", "moe_gmm_dx", "moe_gmm_dw"):
+        assert any(product in line for line in text.splitlines()
+                   if "tpu_custom_call" in line), product
     assert "tpu_custom_call" in text
 
 

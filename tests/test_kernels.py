@@ -126,26 +126,68 @@ def test_decode_attention_ring_buffer_semantics():
 # ---------------------------------------------------------------------------
 
 
+# groups: empty, uneven, and rows past the last group (pairs routed to
+# experts held elsewhere), which must come out zero
+GROUPS = {
+    "uneven": [5, 0, 17, 2],
+    "empty_first_last": [0, 9, 14, 0],
+    "rows_past_groups": [3, 4, 0, 6],
+}
+
+
+def _gmm_case(sizes, dtype, D=24, F=40):
+    M = 32
+    ks = jax.random.split(KEY, 3)
+    x = jax.random.normal(ks[0], (M, D), dtype)
+    w = jax.random.normal(ks[1], (len(sizes), D, F), dtype)
+    dy = jax.random.normal(ks[2], (M, F), dtype)
+    return x, w, jnp.asarray(sizes, jnp.int32), dy
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("E,C,D,F", [(4, 16, 32, 24), (2, 20, 24, 12), (8, 8, 8, 8)])
-def test_gmm_vs_oracle(E, C, D, F, dtype):
-    ks = jax.random.split(KEY, 2)
-    x = jax.random.normal(ks[0], (E, C, D), dtype)
-    w = jax.random.normal(ks[1], (E, D, F), dtype)
-    want = ref.gmm_ref(x, w)
-    got = gmm(x, w, block_c=8, block_f=8, block_d=8, interpret=True)
+@pytest.mark.parametrize("groups", list(GROUPS))
+def test_gmm_vs_oracle(groups, dtype):
+    x, w, sizes, _ = _gmm_case(GROUPS[groups], dtype)
+    want = ref.gmm_ref(x, w, sizes)
+    got = gmm(x, w, sizes, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **tols(dtype)
     )
+    assert not np.any(np.asarray(got, np.float32)[int(sizes.sum()):])
 
 
-def test_gmm_fused_epilogue():
+@pytest.mark.parametrize("groups", list(GROUPS))
+def test_gmm_grads_vs_oracle(groups):
+    """Both backward products (moe_gmm_dx, moe_gmm_dw) against the oracle's VJP."""
+    x, w, sizes, dy = _gmm_case(GROUPS[groups], jnp.float32)
+    _, vjp = jax.vjp(lambda a, b: gmm(a, b, sizes, interpret=True), x, w)
+    _, vjp_ref = jax.vjp(lambda a, b: ref.gmm_ref(a, b, sizes), x, w)
+    for got, want in zip(vjp(dy), vjp_ref(dy)):
+        np.testing.assert_allclose(got, want, **tols(jnp.float32))
+
+
+def test_gmm_pads_rows_to_its_tile():
+    """More rows than one tile, not a multiple of it: the padding stays out."""
+    from repro.kernels import moe_gmm
+
+    M = moe_gmm.ROW_TILE + 40
     ks = jax.random.split(KEY, 2)
-    x = jax.random.normal(ks[0], (2, 8, 16))
-    w = jax.random.normal(ks[1], (2, 16, 8))
-    want = jax.nn.silu(ref.gmm_ref(x, w).astype(jnp.float32))
-    got = gmm(x, w, block_c=8, block_f=8, block_d=8, epilogue="silu", interpret=True)
-    np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
+    x = jax.random.normal(ks[0], (M, 16))
+    w = jax.random.normal(ks[1], (3, 16, 8))
+    sizes = jnp.asarray([200, 0, M - 250], jnp.int32)
+    got = gmm(x, w, sizes, interpret=True)
+    np.testing.assert_allclose(got, ref.gmm_ref(x, w, sizes), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("groups", list(GROUPS))
+def test_gmm_ragged_path_vs_oracle(groups):
+    """The jnp production path (XLA's ragged_dot), forward and gradients."""
+    x, w, sizes, dy = _gmm_case(GROUPS[groups], jnp.float32)
+    out, vjp = jax.vjp(lambda a, b: ref.gmm_ragged(a, b, sizes), x, w)
+    want, vjp_ref = jax.vjp(lambda a, b: ref.gmm_ref(a, b, sizes), x, w)
+    np.testing.assert_allclose(out, want, **tols(jnp.float32))
+    for got, exp in zip(vjp(dy), vjp_ref(dy)):
+        np.testing.assert_allclose(got, exp, **tols(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
